@@ -63,7 +63,7 @@ def run(shape=(128, 128, 64), eb=1e-3, smoke=False):
         shape = (32, 64, 32)
     f = jnp.asarray(make_field("smooth", shape, seed=3))
     rng = float(jnp.max(f) - jnp.min(f))
-    eb_abs = jnp.float32(eb * rng)
+    eb_abs = quant.snap_eb(jnp.float32(eb * rng))
     nbytes = f.size * 4
     rows = []
 

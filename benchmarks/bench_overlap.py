@@ -12,8 +12,11 @@ sweep runs on real multi-pod hardware.
 
 Runs its measurement in a subprocess with 8 fake XLA CPU devices (the main
 benchmark process keeps the default single-device view, like
-tests/test_dist.py). Emits one JSON document; ``benchmarks/run.py
---json-out`` folds it into BENCH_ci.json, the CI perf-trajectory artifact.
+tests/test_dist.py). Every row is therefore CPU rehearsal output, labelled
+``platform: "cpu-rehearsal"``, never a chip timing; on a TPU host the sweep
+refuses to run, since the parent already holds the chip. Emits one JSON
+document; ``benchmarks/run.py --json-out`` folds it into BENCH_ci.json, the
+CI perf-trajectory artifact.
 """
 from __future__ import annotations
 
@@ -34,6 +37,7 @@ MIXES = {
     "skewed": [1 << 16] * 2 + [1 << 12] * 8,
 }
 FULL_SCALE = 4                      # full mode: 4x the smoke element counts
+PLATFORM = "cpu-rehearsal"          # label on every row: fake CPU devices
 
 
 def _child(smoke: bool) -> dict:
@@ -43,7 +47,7 @@ def _child(smoke: bool) -> dict:
 
     from benchmarks.common import timeit
     from repro.dist import bucketed_reduce as bkt
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.dist.compressed_allreduce import (GradCompressionConfig,
                                                  init_error_state,
                                                  reduce_stacked)
@@ -56,7 +60,7 @@ def _child(smoke: bool) -> dict:
 
     rows = []
     for pods in pods_sweep:
-        mesh = compat.make_mesh((pods, N_DEVICES // pods), ("pod", "data"))
+        mesh = make_mesh((pods, N_DEVICES // pods), ("pod", "data"))
         for mix_name, sizes in MIXES.items():
             rng = np.random.default_rng(0)
             g_stack = {f"leaf{i:02d}": jnp.asarray(
@@ -90,14 +94,21 @@ def _child(smoke: bool) -> dict:
                 rows.append({**base, "mode": "bucketed", "bucket_bytes": bb,
                              "n_buckets": plan.n_buckets, "seconds": sec,
                              "wire_mb": round(wire_mb, 3)})
-    return {"rows": rows, "device_count": N_DEVICES, "smoke": smoke}
+    rows = [{**row, "platform": PLATFORM} for row in rows]
+    return {"rows": rows, "device_count": N_DEVICES, "smoke": smoke,
+            "platform": PLATFORM}
 
 
 def main(smoke: bool = False) -> dict:
     """Spawn the fake-device child, print a table, return the JSON dict."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "bench_overlap is a CPU rehearsal on 8 fake devices; on a TPU "
+            "host its child would time the CPU under the chip's name")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEVICES}"
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     root = os.path.join(os.path.dirname(__file__), "..")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -112,6 +123,7 @@ def main(smoke: bool = False) -> dict:
         raise RuntimeError(f"overlap child failed:\n{r.stdout[-2000:]}\n"
                            f"{r.stderr[-2000:]}")
     out = json.loads(r.stdout.splitlines()[-1])
+    print(f"# {PLATFORM}: {N_DEVICES} fake CPU devices, not a chip timing")
     print("mix,pods,mode,bucket_bytes,n_buckets,raw_mb,wire_mb,ms")
     for row in out["rows"]:
         wire = "" if row["wire_mb"] is None else f'{row["wire_mb"]}'

@@ -56,6 +56,8 @@ def main() -> None:
     else:
         todo = list(SECTIONS)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     results: dict[str, object] = {}
     for name in todo:
         print(f"\n===== {name} =====", flush=True)

@@ -7,9 +7,10 @@ bitshuffle Pallas kernel exactly as the paper fuses it into the CUDA kernel.
 
 Phase 2: exclusive prefix-sum of the flags gives each surviving block its
 output offset; compaction copies surviving blocks to the payload. TPU
-adaptation: CUB ``ExclusiveSum`` -> XLA parallel scan (``jnp.cumsum``); the
-scatter-style CUDA compaction -> gather-based compaction
-(``jnp.nonzero(size=...)`` + ``take``), which is the TPU-friendly direction.
+adaptation: CUB ``ExclusiveSum`` -> a blocked XLA scan
+(:func:`exclusive_cumsum`); the scatter-style CUDA compaction -> an index
+scatter of the surviving blocks' positions followed by one gather of their
+words, which is the TPU-friendly direction.
 
 JAX static shapes require a fixed payload *capacity*; ``nnz_blocks`` reports
 the used prefix, and byte accounting uses exact used bytes.
@@ -58,52 +59,93 @@ def unpack_bitflags(bitflags: jax.Array, n_blocks: int) -> jax.Array:
     return bits.reshape(-1)[:n_blocks].astype(bool)
 
 
-def compact_blocks(flags: jax.Array, blocks: jax.Array, *, capacity: int):
-    """XLA phase-2 compaction: (flags bool[n_blocks], blocks u16[n_blocks, 8])
-    -> (bitflags u32[W], payload u16[capacity, 8], nnz i32[]).
+def word_major(shuffled: jax.Array) -> jax.Array:
+    """Flat u16 word stream -> (8, n_blocks): row j is word j of every block.
 
-    The gather-based scan+take formulation, shared by :func:`encode` and the
-    staged kernel path (``kernels.ops.bitshuffle_flag_encode``). The fused
+    The layout payloads and gathers use. Block-major rows of 8 words would
+    be padded 16x by the TPU's (8, 128) tiling; word-major keeps the blocks
+    on lanes.
+    """
+    return shuffled.reshape(-1, BLOCK_WORDS).T
+
+
+SCAN_ROW = 512           # row width of the blocked prefix sum
+
+
+def exclusive_cumsum(x: jax.Array) -> jax.Array:
+    """``jnp.cumsum(x) - x`` for a 1D int32 array, as a two-level scan:
+    prefix sums within rows of ``SCAN_ROW``, then over the row totals.
+
+    Same values; the point is the TPU compiler, which takes about 30 s to
+    compile a flat cumsum of ~1M elements and about 2 s for this form at
+    every size (AOT compiles for a v5e).
+    """
+    n = x.size
+    rows = jnp.pad(x, (0, (-n) % SCAN_ROW)).reshape(-1, SCAN_ROW)
+    inner = jnp.cumsum(rows, axis=1)
+    total = inner[:, -1]
+    out = inner - rows + (jnp.cumsum(total) - total)[:, None]
+    return out.reshape(-1)[:n]
+
+
+def compact_blocks(flags: jax.Array, words: jax.Array, *, capacity: int):
+    """XLA phase-2 compaction: (flags bool[n_blocks], words u16[8, n_blocks])
+    -> (bitflags u32[W], payload u16[8, capacity], nnz i32[]).
+
+    The scan + index-scatter + gather formulation, shared by :func:`encode`
+    and the staged kernel path (``kernels.ops.bitshuffle_flag_encode``). The fused
     megakernel (kernels/fused_compress.py) replaces this wholesale with an
     in-kernel running-offset scatter; this stays as its oracle.
     """
     nnz = jnp.sum(flags, dtype=jnp.int32)
-    (src,) = jnp.nonzero(flags, size=capacity, fill_value=0)
-    payload = blocks[src]
+    # src[k] = index of the k-th flagged block (0 past nnz), as
+    # jnp.nonzero(flags, size=capacity, fill_value=0)
+    n = flags.size
+    dst = jnp.where(flags, exclusive_cumsum(flags.astype(jnp.int32)), capacity)
+    src = jnp.zeros((capacity,), jnp.int32).at[dst].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    payload = words[:, src]
     # slots past nnz replicate block 0; zero them so payload is deterministic
-    payload = jnp.where(jnp.arange(capacity)[:, None] < nnz, payload, 0)
+    payload = jnp.where(jnp.arange(capacity)[None, :] < nnz, payload, 0)
     return pack_bitflags(flags), payload.astype(jnp.uint16), nnz
 
 
 @partial(jax.jit, static_argnames=("capacity",))
 def encode(shuffled: jax.Array, *, capacity: int):
-    """Compact non-zero blocks.
+    """Compact non-zero blocks of a flat shuffled word stream.
 
-    Returns (bitflags u32[W], payload u16[capacity, 8], nnz i32[]).
-    Blocks beyond ``capacity`` are dropped (callers size capacity = n_blocks
-    for lossless-by-construction, or smaller for bounded wire formats with a
-    raw fallback; the dropped count is nnz - capacity when positive).
+    Returns (bitflags u32[W], payload u16[8, capacity], nnz i32[]): payload
+    column k is the k-th surviving 16-byte block. Blocks beyond ``capacity``
+    are dropped (callers size capacity = n_blocks for lossless-by-construction,
+    or smaller for bounded wire formats with a raw fallback; the dropped
+    count is nnz - capacity when positive).
     """
-    blocks = shuffled.reshape(-1, BLOCK_WORDS)
-    flags = jnp.any(blocks != 0, axis=-1)
-    return compact_blocks(flags, blocks, capacity=capacity)
+    words = word_major(shuffled)
+    flags = jnp.any(words != 0, axis=0)
+    return compact_blocks(flags, words, capacity=capacity)
 
 
 @partial(jax.jit, static_argnames=("n_blocks",))
-def decode(bitflags: jax.Array, payload: jax.Array, *, n_blocks: int) -> jax.Array:
-    """Inverse of :func:`encode` -> flat u16 word stream (n_blocks * 8 words).
+def decode_blocks(bitflags: jax.Array, payload: jax.Array, *,
+                  n_blocks: int) -> jax.Array:
+    """Inverse of :func:`encode` -> word-major stream u16[8, n_blocks].
 
     Offsets are the exclusive prefix sum of the unpacked flags; each flagged
-    block gathers its payload slot, unflagged blocks are zero. Blocks whose
+    block gathers its payload column, unflagged blocks are zero. Blocks whose
     offset exceeded capacity at encode time decode to zero (bounded-capacity
     wire mode; exact when capacity >= nnz).
     """
     flags = unpack_bitflags(bitflags, n_blocks)
-    offsets = jnp.cumsum(flags.astype(jnp.int32)) - flags.astype(jnp.int32)  # exclusive
-    cap = payload.shape[0]
+    offsets = exclusive_cumsum(flags.astype(jnp.int32))
+    cap = payload.shape[1]
     in_cap = flags & (offsets < cap)
-    blocks = jnp.where(in_cap[:, None], payload[jnp.minimum(offsets, cap - 1)], 0)
-    return blocks.reshape(-1).astype(jnp.uint16)
+    words = payload[:, jnp.minimum(offsets, cap - 1)]
+    return jnp.where(in_cap[None, :], words, 0).astype(jnp.uint16)
+
+
+def decode(bitflags: jax.Array, payload: jax.Array, *, n_blocks: int) -> jax.Array:
+    """:func:`decode_blocks` as the flat u16 word stream (n_blocks * 8)."""
+    return decode_blocks(bitflags, payload, n_blocks=n_blocks).T.reshape(-1)
 
 
 def used_bytes(n_blocks: int, nnz: jax.Array, n_outliers: jax.Array | None = None,

@@ -21,26 +21,26 @@ Three execution paths, selected by ``FZConfig.use_kernels`` /
     bitshuffle + flagging + phase-2 compaction in a single launch (and the
     full inverse pipeline in another), with the code stream, shuffled words
     and payload offsets living entirely in VMEM/SMEM scratch. With the
-    exact-outlier channel on, quantization routes through the reference to
-    harvest residuals and the rest stays fused (see
-    kernels/ops.py:fused_compress_stages for the documented reason).
+    exact-outlier channel on, quantization runs as the staged kernel, which
+    also writes the residuals, and the rest stays fused (see
+    kernels/ops.py:fused_compress_stages for the reason).
 
 All three produce bit-identical containers and reconstructions (pinned by
 the three-way property suite in tests/test_fz_properties.py).
 
 ``kernel_mode="auto"`` (the default) resolves to one of the concrete paths
-per workload via :mod:`repro.tune`: the persistently cached, parity-gated
-winner of an empirical sweep when one exists for this
-``(backend, op, shape-bucket, dtype, arch)``, else a **backend-aware static
-fallback ordering**. The ordering matters and is deliberate: under the
-Pallas interpreter (every non-TPU backend today) the fused megakernels'
-sequential grid executes in Python and ``BENCH_ci.json`` measures fused
-compress ~4x *slower* than staged — so interpret-class backends fall back
-staged-before-fused, while TPU keeps fused-first (single launch, no HBM
-round-trip for the code stream). Resolution happens in the *eager* public
-wrappers before the jitted inner is entered, so every jit cache key is a
-concrete resolved config — a later cache update can never leave a stale
-"auto" trace behind.
+per workload via :mod:`repro.tune`. On a TPU a fixed rule on the element
+count decides (``tune.dispatch.tpu_fz_impl``): kernels always, the fused
+megakernels only up to ``TPU_FUSED_MAX_ELEMS`` — today 0, since the v5e
+compiler refuses them — and never the jnp reference. Elsewhere it is the
+persistently cached, parity-gated winner of an empirical sweep when one
+exists for this ``(backend, op, shape-bucket, dtype, arch)``, else a static
+ordering: under the Pallas interpreter the fused megakernels' sequential
+grid executes in Python and ``BENCH_ci.json`` measures fused compress ~4x
+*slower* than staged, so interpret-class backends take staged before fused.
+Resolution happens in the *eager* public wrappers before the jitted inner
+is entered, so every jit cache key is a concrete resolved config — a later
+cache update can never leave a stale "auto" trace behind.
 
 Telemetry: the public entry points are thin eager wrappers over the jitted
 pipelines. When called eagerly they bump ``fz_dispatches{op=...}`` counters
@@ -115,7 +115,7 @@ class FZConfig:
 class FZCompressed:
     """Fixed-shape compressed tensor (a pytree; jit/collective-safe)."""
     bitflags: jax.Array        # u32[ceil(n_blocks/32)]
-    payload: jax.Array         # u16[capacity, 8]
+    payload: jax.Array         # u16[8, capacity] — column k is stored block k
     nnz_blocks: jax.Array      # i32[] — used payload prefix
     outlier_idx: jax.Array     # i32[K]
     outlier_val: jax.Array     # i32[K]
@@ -147,15 +147,18 @@ class FZCompressed:
 
 
 def resolve_eb(data: jax.Array, cfg: FZConfig) -> jax.Array:
+    """The container's absolute bound: the configured one, snapped down to
+    8 significant bits (``quant.snap_eb``) so that it holds without an f32
+    rounding allowance."""
     if cfg.eb_mode == "abs":
-        return jnp.float32(cfg.eb)
+        return quant.snap_eb(jnp.float32(cfg.eb))
     if cfg.eb_mode == "rel":
         rng = jnp.max(data) - jnp.min(data)
         # floor at eb*max|x|: keeps constant fields finite (range == 0) and
         # bounds pre-quantization codes by 1/(2*eb) — no int32 overflow
         maxabs = jnp.max(jnp.abs(data))
         eb = cfg.eb * jnp.maximum(rng, maxabs).astype(jnp.float32)
-        return jnp.maximum(eb, jnp.float32(1e-30))
+        return quant.snap_eb(jnp.maximum(eb, jnp.float32(1e-30)))
     raise ValueError(f"unknown eb_mode {cfg.eb_mode!r}")
 
 
@@ -167,11 +170,9 @@ def _resolved(cfg: FZConfig, direction: str, n: int, dtype_name: str) -> FZConfi
     """Resolve ``kernel_mode="auto"`` to a concrete execution path.
 
     Called by every eager public entry point *before* the jitted inner, so
-    jit caches key on the resolved config. The tuned winner comes from
-    :func:`repro.tune.resolve_fz` (cache hit) or its backend-aware static
-    fallback (cache miss): staged-before-fused on interpret-class backends
-    — the measured 4x fused-compress interpreter regression — fused-first
-    on TPU. See the module docstring for the full ordering rationale.
+    jit caches key on the resolved config. :func:`repro.tune.resolve_fz`
+    applies the TPU size rule, or elsewhere the cached winner or the static
+    staged-before-fused ordering. See the module docstring.
     """
     if not (cfg.use_kernels and cfg.kernel_mode == "auto"):
         return cfg
@@ -182,15 +183,15 @@ def _resolved(cfg: FZConfig, direction: str, n: int, dtype_name: str) -> FZConfi
     return dataclasses.replace(cfg, kernel_mode=impl)
 
 
-def _static_auto(cfg: FZConfig) -> FZConfig:
+def _static_auto(cfg: FZConfig, n: int) -> FZConfig:
     """Last-ditch "auto" resolution for internal callers that bypass the
     public wrappers (direct ``_*_jit`` use): static backend fallback only —
-    deterministic per backend, no cache lookup, so a jit trace keyed on an
-    "auto" config can never go stale against a cache update."""
+    deterministic per backend and size, no cache lookup, so a jit trace
+    keyed on an "auto" config can never go stale against a cache update."""
     if not (cfg.use_kernels and cfg.kernel_mode == "auto"):
         return cfg
     from repro.tune import dispatch
-    return dataclasses.replace(cfg, kernel_mode=dispatch.fz_fallback_mode())
+    return dataclasses.replace(cfg, kernel_mode=dispatch.fz_fallback_mode(n))
 
 
 def _stages(cfg: FZConfig):
@@ -210,9 +211,9 @@ def _stages(cfg: FZConfig):
         with obs.span("fz.stage.shuffle_encode", backend="reference"):
             shuffled = shuffle.bitshuffle(codes_flat)
             return enc.encode(shuffled, capacity=capacity)
-    def ref_unshuffle(words_flat):
+    def ref_unshuffle(words):
         with obs.span("fz.stage.unshuffle", backend="reference"):
-            return shuffle.bitunshuffle(words_flat)
+            return shuffle.bitunshuffle(words.T.reshape(-1))
     return ref_quant, ref_shuffle_encode, ref_unshuffle
 
 
@@ -237,7 +238,7 @@ def _path(cfg: FZConfig) -> str:
 
 def _count_dispatch(op: str, cfg: FZConfig, out: FZCompressed | None = None) -> None:
     """One eager jit launch = one dispatch. Callers gate on
-    ``jax.core.trace_state_clean()`` so traces are never tallied as work."""
+    ``jax.core.trace_ctx.is_top_level()`` so traces are never tallied as work."""
     obs.counter("fz_dispatches", op=op, path=_path(cfg)).inc()
     if out is not None:
         obs.histogram("fz_raw_bytes", op=op).observe(out.raw_bytes())
@@ -246,7 +247,7 @@ def _count_dispatch(op: str, cfg: FZConfig, out: FZCompressed | None = None) -> 
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _compress_jit(data: jax.Array, cfg: FZConfig) -> FZCompressed:
-    cfg = _static_auto(cfg)
+    cfg = _static_auto(cfg, data.size)
     dtype_name = _source_dtype_name(data)
     data = data.astype(jnp.float32)
     eb = resolve_eb(data, cfg)
@@ -260,7 +261,7 @@ def compress(data: jax.Array, cfg: FZConfig) -> FZCompressed:
     accounting; the quantization math itself always runs in float32.
     """
     cfg = _resolved(cfg, "compress", int(data.size), _source_dtype_name(data))
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return _compress_jit(data, cfg)
     with obs.span("fz.compress", n=int(data.size), path=_path(cfg)):
         out = _compress_jit(data, cfg)
@@ -271,11 +272,11 @@ def compress(data: jax.Array, cfg: FZConfig) -> FZCompressed:
 @partial(jax.jit, static_argnames=("cfg",))
 def _compress_with_eb_jit(data: jax.Array, eb_abs: jax.Array,
                           cfg: FZConfig) -> FZCompressed:
-    cfg = _static_auto(cfg)
+    cfg = _static_auto(cfg, data.size)
     dtype_name = _source_dtype_name(data)
     data = data.astype(jnp.float32)
     eb = jnp.maximum(jnp.asarray(eb_abs, jnp.float32), jnp.float32(1e-30))
-    return _compress_core(data, eb, cfg, dtype_name)
+    return _compress_core(data, quant.snap_eb(eb), cfg, dtype_name)
 
 
 def compress_with_eb(data: jax.Array, eb_abs: jax.Array, cfg: FZConfig) -> FZCompressed:
@@ -289,7 +290,7 @@ def compress_with_eb(data: jax.Array, eb_abs: jax.Array, cfg: FZConfig) -> FZCom
     a single jit trace.
     """
     cfg = _resolved(cfg, "compress", int(data.size), _source_dtype_name(data))
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return _compress_with_eb_jit(data, eb_abs, cfg)
     with obs.span("fz.compress", n=int(data.size), path=_path(cfg)):
         out = _compress_with_eb_jit(data, eb_abs, cfg)
@@ -323,7 +324,7 @@ def _compress_core(data: jax.Array, eb: jax.Array, cfg: FZConfig,
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _decompress_jit(c: FZCompressed, cfg: FZConfig) -> jax.Array:
-    cfg = _static_auto(cfg)
+    cfg = _static_auto(cfg, c.n)
     if _fused(cfg):
         from repro.kernels import ops as kops
         return kops.fused_decompress(
@@ -332,7 +333,8 @@ def _decompress_jit(c: FZCompressed, cfg: FZConfig) -> jax.Array:
             outlier_idx=c.outlier_idx if cfg.exact_outliers else None,
             outlier_val=c.outlier_val if cfg.exact_outliers else None)
     _, _, unshuffle = _stages(cfg)
-    words = enc.decode(c.bitflags, c.payload, n_blocks=FZConfig.n_blocks(c.n))
+    words = enc.decode_blocks(c.bitflags, c.payload,
+                              n_blocks=FZConfig.n_blocks(c.n))
     codes = unshuffle(words)[: c.n]
     oidx = c.outlier_idx if cfg.exact_outliers else None
     oval = c.outlier_val if cfg.exact_outliers else None
@@ -343,7 +345,7 @@ def _decompress_jit(c: FZCompressed, cfg: FZConfig) -> jax.Array:
 def decompress(c: FZCompressed, cfg: FZConfig) -> jax.Array:
     """Inverse pipeline: decode -> bit-unshuffle -> inverse Lorenzo -> dequant."""
     cfg = _resolved(cfg, "decompress", c.n, c.dtype_name)
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return _decompress_jit(c, cfg)
     with obs.span("fz.decompress", n=c.n, path=_path(cfg)):
         out = _decompress_jit(c, cfg)
@@ -370,7 +372,7 @@ def roundtrip(data: jax.Array, cfg: FZConfig):
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _compress_batch_jit(pages_flat, eb_abs, cfg: FZConfig):
-    cfg = _static_auto(cfg)
+    cfg = _static_auto(cfg, pages_flat.size // pages_flat.shape[0])
     return jax.vmap(lambda d: _compress_with_eb_jit(d, eb_abs, cfg))(pages_flat)
 
 
@@ -382,7 +384,7 @@ def compress_batch_with_eb(pages_flat: jax.Array, eb_abs: jax.Array,
     kvpool cold tier's batched park path."""
     cfg = _resolved(cfg, "compress", int(pages_flat.size // pages_flat.shape[0]),
                     _source_dtype_name(pages_flat))
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return _compress_batch_jit(pages_flat, eb_abs, cfg)
     with obs.span("fz.compress_batch", rows=int(pages_flat.shape[0]),
                   path=_path(cfg)):
@@ -394,7 +396,7 @@ def compress_batch_with_eb(pages_flat: jax.Array, eb_abs: jax.Array,
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _decompress_batch_jit(comp: FZCompressed, cfg: FZConfig):
-    cfg = _static_auto(cfg)
+    cfg = _static_auto(cfg, comp.n)
     return jax.vmap(lambda c: _decompress_jit(c, cfg))(comp)
 
 
@@ -402,7 +404,7 @@ def decompress_batch(comp: FZCompressed, cfg: FZConfig) -> jax.Array:
     """vmap ``decompress`` over a leaf-stacked container batch (one counted
     dispatch) — the kvpool's batched transient cold read."""
     cfg = _resolved(cfg, "decompress", comp.n, comp.dtype_name)
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return _decompress_batch_jit(comp, cfg)
     with obs.span("fz.decompress_batch", rows=int(comp.payload.shape[0]),
                   path=_path(cfg)):
@@ -488,9 +490,9 @@ def to_bytes(c: FZCompressed, cfg: FZConfig, *, entropy: bool | str = "auto",
     if c.dtype_name not in _DTYPE_CODES:
         raise FZFormatError(f"unserializable container dtype {c.dtype_name!r}")
     nnz = int(c.nnz_blocks)
-    rows = min(nnz, int(c.payload.shape[0]))
+    rows = min(nnz, int(c.payload.shape[1]))
     n_out = int(c.n_outliers)
-    payload = np.asarray(c.payload)[:rows].astype("<u2").tobytes()
+    payload = np.asarray(c.payload)[:, :rows].T.astype("<u2").tobytes()
 
     selected = False
     body = payload
@@ -594,8 +596,8 @@ def from_bytes(raw: bytes, *, capacity: int | None = None,
     cap = max(rows, 1) if capacity is None else capacity
     if cap < rows:
         raise FZFormatError(f"capacity {cap} < {rows} stored payload rows")
-    payload = np.zeros((cap, enc.BLOCK_WORDS), np.uint16)
-    payload[:rows] = np.frombuffer(body, "<u2").reshape(rows, enc.BLOCK_WORDS)
+    payload = np.zeros((enc.BLOCK_WORDS, cap), np.uint16)
+    payload[:, :rows] = np.frombuffer(body, "<u2").reshape(rows, enc.BLOCK_WORDS).T
 
     if flags & FLAG_OUTLIERS:
         oidx = _np_slice(raw, "<i4", n_out, off, "outlier idx")
@@ -655,8 +657,8 @@ def _from_legacy_bytes(raw: memoryview, *, capacity: int | None,
     oval = np.frombuffer(raw, "<i4", n_out, off)
 
     cap = max(nnz, 1) if capacity is None else capacity
-    payload = np.zeros((cap, enc.BLOCK_WORDS), np.uint16)
-    payload[:nnz] = rows
+    payload = np.zeros((enc.BLOCK_WORDS, cap), np.uint16)
+    payload[:, :nnz] = rows.T
     ocap = max(n_out, 1) if outlier_capacity is None else outlier_capacity
     oi = np.full((ocap,), n, np.int32)
     oi[:n_out] = oidx

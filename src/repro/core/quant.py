@@ -2,7 +2,7 @@
 
 The only lossy stage of the pipeline:
 
-    q_i   = round(d_i / (2 * eb))          # pre-quantization (error <= eb)
+    q_i   = round(d_i * (1 / (2 * eb)))    # pre-quantization (error <= eb)
     delta = Lorenzo(q)                      # integer finite differences (exact)
     code  = sign_magnitude_u16(delta)       # MSB = sign, no radius shift,
                                             # no separate outlier stream
@@ -108,6 +108,80 @@ def from_codes(code: jax.Array, *, code_mode: str = "sign_mag") -> jax.Array:
 # Full dual-quantization forward / inverse
 # ---------------------------------------------------------------------------
 
+def inv_two_eb(eb: jax.Array) -> jax.Array:
+    """The correctly rounded float32 ``1 / (2 * eb)``, in integer arithmetic.
+
+    Every element is quantized by *multiplying* with this one value. An f32
+    multiply rounds the same under XLA and in the Pallas kernel, but a
+    divide need not: the TPU has no divide unit, each compiler expands its
+    own, and XLA may compute a scalar on another unit than a vector. Long
+    division of the mantissas gives the same bits everywhere. Needs
+    ``2 * eb`` to be a normal float32 below 2**126.
+    """
+    y = 2.0 * jnp.asarray(eb, jnp.float32)          # exact
+    bits = jax.lax.bitcast_convert_type(y, jnp.int32)
+    e = (bits >> 23) & 0xFF
+    m = (bits & 0x7FFFFF) | 0x800000                # y = m * 2**(e - 150)
+    rem, q = jnp.ones_like(m), jnp.zeros_like(m)
+    for _ in range(47):                             # q = 2**47 // m
+        rem = rem * 2
+        bit = (rem >= m).astype(jnp.int32)
+        rem, q = rem - bit * m, q * 2 + bit
+    # q is in [2**23, 2**24]; round to nearest (a tie would need m | 2**48,
+    # i.e. an exact quotient). Then 1/y = q * 2**(103 - e).
+    q = q + (2 * rem >= m).astype(jnp.int32)
+    carry = q >> 24                                 # q == 2**24: a power of 2
+    out = ((253 - e + carry) << 23) | jnp.where(carry == 1, 0, q - 0x800000)
+    return jax.lax.bitcast_convert_type(out, jnp.float32)
+
+
+def snap_eb(eb: jax.Array) -> jax.Array:
+    """The largest bound <= ``eb`` with 8 significant bits.
+
+    Then the step ``2 * eb`` has 8 significant bits too, so every
+    reconstruction ``q * 2eb`` with ``|q| < 2**16`` is exact in float32 and
+    :func:`quantize_scaled` can hold ``|x - q * 2eb| <= eb`` with no rounding
+    allowance. It costs under 2**-7 of the step (< 0.012 bits per value).
+    """
+    bits = jax.lax.bitcast_convert_type(jnp.asarray(eb, jnp.float32), jnp.int32)
+    return jax.lax.bitcast_convert_type(bits & ~0xFFFF, jnp.float32)
+
+
+def step_scalars(eb: jax.Array) -> jax.Array:
+    """float32 ``[inv, two_eb, tol]`` that :func:`quantize_scaled` takes:
+    ``inv_two_eb(eb)``, ``2 * eb`` and ``eb``. The bound must be snapped
+    (:func:`snap_eb`); ``fz`` snaps it where a container is built."""
+    eb = jnp.asarray(eb, jnp.float32)
+    return jnp.stack([inv_two_eb(eb), 2.0 * eb, eb])
+
+
+def quantize_scaled(x: jax.Array, inv, two_eb, tol) -> jax.Array:
+    """float32 ``x`` -> int32 ``q``, the nearest multiple of the step.
+
+    ``q = rint(x * inv)``, then moved by one where the product's rounding
+    picked the wrong neighbour: where the reconstruction ``q * two_eb``
+    (what the decoder computes) lies further than ``tol`` from ``x``. The
+    fix runs only where that reconstruction is exact (a snapped bound and
+    ``|q| < 2**16``), so a compiler's choice to fuse the multiply and the
+    subtract cannot change it; there ``|x - q * 2eb| <= eb`` holds exactly.
+    Shared by the reference and the Pallas kernels, in the same float32
+    operations, so both give the same bits.
+    """
+    qf = jnp.rint(x * inv)
+    d = x - qf * two_eb
+    exact = jnp.abs(qf) < 2.0 ** 16
+    step = (jnp.where(exact & (d > tol), 1, 0)
+            - jnp.where(exact & (d < -tol), 1, 0))
+    return qf.astype(jnp.int32) + step
+
+
+def prequantize(data: jax.Array, eb: jax.Array) -> jax.Array:
+    """Pre-quantization of the reference: :func:`quantize_scaled` of the
+    data in float32."""
+    inv, two_eb, tol = step_scalars(eb)
+    return quantize_scaled(data.astype(jnp.float32), inv, two_eb, tol)
+
+
 @partial(jax.jit, static_argnames=("code_mode", "outlier_capacity"))
 def dual_quantize(data: jax.Array, eb: jax.Array, *, code_mode: str = "sign_mag",
                   outlier_capacity: int = 0):
@@ -118,26 +192,41 @@ def dual_quantize(data: jax.Array, eb: jax.Array, *, code_mode: str = "sign_mag"
     recorded against their flat index (beyond-paper strict-error-bound mode).
 
     Preconditions (shared with SZ-family quantizers operating in float32):
+      * ``eb`` is snapped (:func:`snap_eb`), as :func:`quantize_scaled`
+        needs;
       * codes fit int32: ``max|d| / (2*eb) < 2**31`` (else q wraps; no outlier
         channel can repair that);
       * strict error bound additionally needs ``range/(2*eb) < ~2**21`` so the
-        f32 divide/rint/multiply round-trip stays within 1 q-unit. The paper's
+        f32 multiply/rint/multiply round-trip stays within 1 q-unit. The paper's
         own evaluation range (rel eb 1e-2..1e-4, q <= 5000) sits far inside;
         beyond it the bound degrades gracefully to eb + O(ulp(data)).
     """
-    q = jnp.rint(data.astype(jnp.float32) / (2.0 * eb)).astype(jnp.int32)
+    q = prequantize(data, eb)
     delta = lorenzo_delta(q)
-    codes, over, resid = to_codes(delta, code_mode=code_mode)
-    n = codes.size
+    codes, _, resid = to_codes(delta, code_mode=code_mode)
+    return (codes, *collect_outliers(resid, outlier_capacity))
+
+
+def collect_outliers(resid: jax.Array, outlier_capacity: int):
+    """int32 residuals of :func:`to_codes` -> (outlier_idx i32[K],
+    outlier_val i32[K], n_outliers i32[]).
+
+    A residual is nonzero exactly where the code saturated. The first K of
+    them, in flat order, keep their index and exact value; unused slots hold
+    index ``n`` and value 0. K = 0 records only the count (paper mode).
+    """
+    flat = resid.ravel()
+    n = flat.size
+    over = flat != 0
     n_over = jnp.sum(over, dtype=jnp.int32)
     if outlier_capacity > 0:
-        (idx,) = jnp.nonzero(over.ravel(), size=outlier_capacity, fill_value=n)
-        val = jnp.where(idx < n, resid.ravel()[jnp.minimum(idx, n - 1)], 0)
+        (idx,) = jnp.nonzero(over, size=outlier_capacity, fill_value=n)
+        val = jnp.where(idx < n, flat[jnp.minimum(idx, n - 1)], 0)
         idx = idx.astype(jnp.int32)
     else:
         idx = jnp.zeros((0,), jnp.int32)
         val = jnp.zeros((0,), jnp.int32)
-    return codes, idx, val, n_over
+    return idx, val, n_over
 
 
 @partial(jax.jit, static_argnames=("shape", "code_mode"))

@@ -26,8 +26,6 @@ that idea inside the training/serving stack, one module per use case:
     compression", serve/engine.py) never has to be regathered on one device.
     The jnp partials are the oracle; ``use_kernels`` swaps in the Pallas
     KV-tile kernel (``repro.kernels.flash_decode``) per shard.
-  * ``compat`` — version-portability shims for the mesh / shard_map APIs so
-    the same code runs on the pinned jax as well as current releases.
 """
-from . import (bucketed_reduce, compat, compressed_allreduce,  # noqa: F401
+from . import (bucketed_reduce, compressed_allreduce,  # noqa: F401
                flash_decode, sharding)

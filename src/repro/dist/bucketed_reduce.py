@@ -64,7 +64,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.core import fz
-from . import compat
 from .compressed_allreduce import (GradCompressionConfig, _compressible,
                                    pod_hop_body, reference_hop,
                                    wire_bytes_per_leaf)
@@ -165,7 +164,7 @@ def assign_buckets(grads_abstract: Any, cfg: GradCompressionConfig) -> BucketPla
     # analytic wire bytes are known at plan time (the hop itself runs inside
     # jit, where nothing may be recorded) — publish them as per-bucket gauges
     # so step_report can join bytes onto the bucket spans without an HLO pass
-    if jax.core.trace_state_clean():
+    if jax.core.trace_ctx.is_top_level():
         obs.gauge("dist_n_buckets").set(plan.n_buckets)
         for b in plan.buckets:
             obs.gauge("dist_bucket_wire_bytes", bucket=b.tag).set(b.wire_bytes)
@@ -230,7 +229,7 @@ def _boundary_fwd(tree):
 
 
 def _boundary_bwd(_, ct):
-    return (compat.optimization_barrier(ct),)
+    return (jax.lax.optimization_barrier(ct),)
 
 
 _boundary.defvjp(_boundary_fwd, _boundary_bwd)
@@ -269,10 +268,11 @@ def _bucket_hop(xs: list[jax.Array], fzc: fz.FZConfig, mesh, tag: str):
         outs = [pod_hop_body(x_sh[0], fzc) for x_sh in xs_sh]
         return tuple(r for r, _ in outs), tuple(e for _, e in outs)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(P("pod") for _ in xs),
-        out_specs=(tuple(P() for _ in xs), tuple(P("pod") for _ in xs)))
+        out_specs=(tuple(P() for _ in xs), tuple(P("pod") for _ in xs)),
+        check_vma=False)
     # the span installs a named scope containing the bucket tag — that is
     # what hlo_cost's tag_pattern keys cross-pod bytes on (and what lets
     # step_report join dist_bucket_wire_bytes onto this span's timing); the

@@ -176,11 +176,22 @@ def pod_hop_body(xi: jax.Array, fzc: fz.FZConfig) -> tuple[jax.Array, jax.Array]
     """
     c = fz.compress(xi, fzc)
     c_all = jax.tree.map(lambda leaf: jax.lax.all_gather(leaf, "pod"), c)
-    d = jax.vmap(lambda ci: fz.decompress(ci, fzc))(c_all)   # (n_pods, n)
-    red = jnp.mean(d, axis=0)
-    mine = jax.lax.dynamic_index_in_dim(
-        d, jax.lax.axis_index("pod"), 0, keepdims=False)
-    return red, (xi - mine)[None]
+    me = jax.lax.axis_index("pod")
+
+    # one pod's container at a time: a vmap would hold every pod's (n,)
+    # reconstruction at once, which at a 64000x4096 embedding leaf is what
+    # overflows a 16 GB chip
+    def add_pod(carry, ci_i):
+        total, mine = carry
+        ci, i = ci_i
+        d = fz.decompress(ci, fzc)
+        return (total + d, jnp.where(i == me, d, mine)), None
+
+    zero = jnp.zeros(xi.shape, jnp.float32)
+    n_pods = c_all.payload.shape[0]
+    (total, mine), _ = jax.lax.scan(
+        add_pod, (zero, zero), (c_all, jnp.arange(n_pods)))
+    return total / n_pods, (xi - mine)[None]
 
 
 def reduce_stacked(g_stack: Any, err_state: Any, cfg: GradCompressionConfig,
@@ -206,17 +217,15 @@ def reduce_stacked(g_stack: Any, err_state: Any, cfg: GradCompressionConfig,
 
     def sharded_roundtrip(x):
         """x: (n_pods, n) -> (mean (n,), residual (n_pods, n)) via shard_map."""
-        from repro.dist import compat
-
         def body(x_sh):
             return pod_hop_body(x_sh[0], fzc)   # x_sh[0]: this pod's slice
 
         # fully manual (axis_names=None): data/model must also be manual so
         # the partitioner can never slice the FZ pipeline's scan axis — the
         # body is replicated across them (in/out specs only use "pod")
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"),),
-            out_specs=(P(), P("pod")))(x)
+            out_specs=(P(), P("pod")), check_vma=False)(x)
 
     def one(g, e):
         n_pods = g.shape[0]

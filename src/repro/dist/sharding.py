@@ -68,16 +68,19 @@ def _mesh_axis_size(mesh, axis: str) -> int:
     return int(mesh.shape[axis])
 
 
-def resolve_spec(logical: Sequence[str | None], shape: Sequence[int], mesh) -> P:
+def resolve_spec(logical: Sequence[str | None], shape: Sequence[int], mesh,
+                 exclude: Sequence[str] = ()) -> P:
     """Resolve a per-dimension logical spec into a PartitionSpec for ``mesh``.
 
     Divisibility fallback: for each dimension, the longest suffix of the
     assigned mesh-axis tuple whose total size divides the dimension is used
     (suffix, so ``dp`` prefers the large in-pod ``data`` axis over ``pod``
     when the full span does not divide); no suffix divides -> replicated.
+    Mesh axes in ``exclude`` are taken already (e.g. ``pod`` inside a vmap
+    over pods whose mapped dimension carries it).
     """
     table = logical_to_mesh_axes(mesh)
-    used: set[str] = set()
+    used: set[str] = set(exclude)
     entries: list[Any] = []
     for i, dim in enumerate(shape):
         name = logical[i] if i < len(logical) else None

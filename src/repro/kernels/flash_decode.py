@@ -47,7 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.kernelspec import BlockDecl, KernelSpec, register_spec
+from repro.analysis.kernelspec import (SMEM, BlockDecl, KernelSpec,
+                                       register_spec)
 
 NEG_INF = -1e30        # same finite stand-in as dist/flash_decode.py
 KV_TILE = 128          # default KV positions per grid step (TPU lane width)
@@ -62,12 +63,13 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, num_ref, den_ref,
                          *, tile: int):
     """One (batch row, KV tile) grid step of the online softmax.
 
-    len_ref: (1, 1) i32 effective valid length (already offset-adjusted);
+    len_ref: (B,) i32 effective valid lengths in SMEM (scalar prefetch,
+    already offset-adjusted);
     q_ref: (1, KVH, G, D) f32 pre-scaled query; k_ref/v_ref: (1, 1, tile,
     KVH, D) cache tile; m/num/den refs: the (1, KVH, G[, D]) f32 partials,
     revisited across every tile of the row and accumulated in place.
     """
-    t = pl.program_id(1)
+    b, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
@@ -75,7 +77,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, num_ref, den_ref,
         num_ref[0] = jnp.zeros(num_ref.shape[1:], jnp.float32)
         den_ref[0] = jnp.zeros(den_ref.shape[1:], jnp.float32)
 
-    length = len_ref[0, 0]
+    length = len_ref[b]
     q = q_ref[0]                                     # (KVH, G, D) f32
     k = k_ref[0, 0].astype(jnp.float32)              # (tile, KVH, D)
     v = v_ref[0, 0].astype(jnp.float32)
@@ -102,20 +104,24 @@ def _decode_partials_tiles(q4: jax.Array, k_tiles: jax.Array, v_tiles: jax.Array
     shapes (B, KVH, G), (B, KVH, G, D), (B, KVH, G), all f32."""
     B, KVH, G, D = q4.shape
     T, tile = k_tiles.shape[1], k_tiles.shape[2]
-    m, num, den = pl.pallas_call(
-        functools.partial(_flash_decode_kernel, tile=tile),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # the lengths ride in SMEM: a (1, 1) VMEM block of a (B, 1) array
+        # breaks Mosaic's (8, 128) block rule
+        num_scalar_prefetch=1,
         grid=(B, T),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, t: (b, 0)),
-            pl.BlockSpec((1, KVH, G, D), lambda b, t: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, tile, KVH, D), lambda b, t: (b, t, 0, 0, 0)),
-            pl.BlockSpec((1, 1, tile, KVH, D), lambda b, t: (b, t, 0, 0, 0)),
+            pl.BlockSpec((1, KVH, G, D), lambda b, t, _: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, tile, KVH, D), lambda b, t, _: (b, t, 0, 0, 0)),
+            pl.BlockSpec((1, 1, tile, KVH, D), lambda b, t, _: (b, t, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, KVH, G), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, KVH, G, D), lambda b, t: (b, 0, 0, 0)),
-            pl.BlockSpec((1, KVH, G), lambda b, t: (b, 0, 0)),
-        ],
+            pl.BlockSpec((1, KVH, G), lambda b, t, _: (b, 0, 0)),
+            pl.BlockSpec((1, KVH, G, D), lambda b, t, _: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KVH, G), lambda b, t, _: (b, 0, 0)),
+        ])
+    m, num, den = pl.pallas_call(
+        functools.partial(_flash_decode_kernel, tile=tile),
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, KVH, G), jnp.float32),
             jax.ShapeDtypeStruct((B, KVH, G, D), jnp.float32),
@@ -124,10 +130,10 @@ def _decode_partials_tiles(q4: jax.Array, k_tiles: jax.Array, v_tiles: jax.Array
         # batch rows are independent ("parallel"); the KV-tile axis carries
         # the online-softmax state in the revisited output blocks, so it
         # must stay sequential ("arbitrary") — checked by repro.analysis
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(length_eff.reshape(B, 1), q4, k_tiles, v_tiles)
+    )(length_eff, q4, k_tiles, v_tiles)
     return m, num, den
 
 
@@ -147,8 +153,9 @@ def kernel_spec(B: int, S: int, KVH: int, G: int, D: int,
     return KernelSpec(
         name="flash_decode", module=__name__, grid=(B, T),
         in_blocks=(
-            BlockDecl("len", (1, 1), "int32",
-                      index_map=lambda b, t: (b, 0)),
+            # scalar-prefetched lengths: SMEM-resident for the whole launch
+            BlockDecl("len", (B,), "int32", memory=SMEM,
+                      index_map=lambda b, t: (0,)),
             BlockDecl("q", (1, KVH, G, D), "float32",
                       index_map=lambda b, t: (b, 0, 0, 0)),
             BlockDecl("k", (1, 1, tile, KVH, D), "float32",

@@ -22,8 +22,8 @@ the clamped final band and mask everything to the zero pad.
 Phase-2 compaction (the decoupled-lookback analogue): the running payload
 offset rides in SMEM scratch across sequential grid steps; each step computes
 its blocks' global offsets as ``smem_offset + local exclusive cumsum`` and
-scatters surviving 16-byte blocks straight into the payload output (row
-``capacity`` is a write-off trash slot for beyond-capacity blocks, sliced off
+scatters surviving 16-byte blocks straight into the word-major payload
+output (column ``capacity`` is a write-off trash slot for beyond-capacity blocks, sliced off
 by the wrapper). ``jnp.nonzero`` and the full-stream materialization are gone.
 
 TPU notes: the sequential carry requires ``dimension_semantics=("arbitrary",)``
@@ -47,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.kernelspec import (BlockDecl, KernelSpec, ScratchDecl,
                                        register_spec)
+from repro.core import quant as _quant
 from . import bitshuffle_flag as _bsf
 from . import lorenzo_quant as _lq
 
@@ -56,7 +57,8 @@ GROUPS_PER_TILE = _bsf.GROUPS_PER_TILE            # 256
 BLOCK_WORDS = _bsf.BLOCK_WORDS                    # 8 u16 words per zero block
 BLOCKS_PER_TILE = _bsf.BLOCKS_PER_TILE            # 512
 FLAG_WORDS_PER_TILE = BLOCKS_PER_TILE // 32       # 16 packed u32 per tile
-ROW_1D = 1024                                     # flattened-1D row width
+ROW_1D = _lq.ROW_1D                               # flattened-1D row width
+ENCODE_TILES = 8                                  # tiles per fused_shuffle_encode step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,16 +164,17 @@ def _pack_flag_words(fv: jax.Array, nb: int) -> jax.Array:
 def _compact_into_payload(payload_ref, blocks, fv, base_off, capacity: int):
     """Scatter surviving blocks at ``base_off + local exclusive cumsum``.
 
-    Row ``capacity`` of the payload ref is the trash slot: non-surviving and
-    beyond-capacity blocks land there (reference semantics drop them).
-    Returns this step's survivor count.
+    The payload ref is word-major (8, capacity + 1), as the container's;
+    column ``capacity`` is the trash slot: non-surviving and beyond-capacity
+    blocks land there (reference semantics drop them). Returns this step's
+    survivor count.
     """
     nb = fv.shape[0]
     fv_i = fv.astype(jnp.int32).reshape(1, nb)
     excl = (jnp.cumsum(fv_i, axis=1) - fv_i).reshape(nb)
     off = base_off + excl
     idx = jnp.where(fv & (off < capacity), off, capacity)
-    payload_ref[idx] = blocks
+    payload_ref[:, idx] = blocks.T
     return jnp.sum(fv_i, dtype=jnp.int32)
 
 
@@ -183,7 +186,7 @@ def _make_compress_kernel(p: StreamPlan, capacity: int, code_mode: str):
     m, wmax = p.m, p.wmax_compress
     nb = wmax * BLOCKS_PER_TILE
 
-    def kernel(x_ref, halo_ref, eb_ref, bitflags_ref, payload_ref, nnz_ref,
+    def kernel(x_ref, halo_ref, step_ref, bitflags_ref, payload_ref, nnz_ref,
                carry_ref, sm_ref):
         i = pl.program_id(0)
 
@@ -193,13 +196,14 @@ def _make_compress_kernel(p: StreamPlan, capacity: int, code_mode: str):
             sm_ref[1] = 0                        # running payload offset
             sm_ref[2] = 0                        # tiles emitted so far
             carry_ref[...] = jnp.zeros((1, TILE), jnp.uint16)
-            payload_ref[...] = jnp.zeros((capacity + 1, BLOCK_WORDS), jnp.uint16)
+            payload_ref[...] = jnp.zeros((BLOCK_WORDS, capacity + 1), jnp.uint16)
             nnz_ref[0, 0] = 0
 
-        codes = _lq.band_codes(x_ref[...], halo_ref[...], 2.0 * eb_ref[0, 0],
+        codes = _lq.band_codes(x_ref[...], halo_ref[...],
+                               tuple(step_ref[0, j] for j in range(3)),
                                ndim=p.kern_nd, code_mode=code_mode,
-                               is_first=i == 0)
-        flat = codes.reshape(1, m)
+                               keep_halo=jnp.minimum(i, 1))
+        flat = codes.astype(jnp.uint16).reshape(1, m)
         # zero everything past the real data: the stream then matches the
         # reference's zero-padded flat code stream exactly, including the
         # grid's flush steps past the last band (whose clamped input band is
@@ -245,7 +249,7 @@ def _make_compress_kernel(p: StreamPlan, capacity: int, code_mode: str):
 @functools.partial(jax.jit, static_argnames=("code_mode", "capacity", "interpret"))
 def fused_compress(data: jax.Array, eb: jax.Array, *, capacity: int,
                    code_mode: str = "sign_mag", interpret: bool = False):
-    """float (1-3)D -> (bitflags u32[W], payload u16[capacity, 8], nnz i32[]).
+    """float (1-3)D -> (bitflags u32[W], payload u16[8, capacity], nnz i32[]).
 
     Bit-identical to ``enc.encode(shuffle.bitshuffle(pad(quantize(data))))``
     with the code stream never leaving VMEM.
@@ -267,26 +271,26 @@ def fused_compress(data: jax.Array, eb: jax.Array, *, capacity: int,
         return (jnp.maximum(jnp.minimum(i, p.bands - 1) * p.band - 1, 0),
                 *zeros_trail)
 
-    eb_arr = jnp.reshape(jnp.asarray(eb, jnp.float32), (1, 1))
+    step = jnp.reshape(_quant.step_scalars(eb), (1, 3))
     bitflags, payload, nnz = pl.pallas_call(
         _make_compress_kernel(p, capacity, code_mode),
         grid=(steps,),
         in_specs=[pl.BlockSpec(band_block, band_index),
                   pl.BlockSpec((1, *p.trailing), halo_index),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                  pl.BlockSpec((1, 3), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((1, fw_pad), lambda i: (0, 0)),
-                   pl.BlockSpec((capacity + 1, BLOCK_WORDS), lambda i: (0, 0)),
+                   pl.BlockSpec((BLOCK_WORDS, capacity + 1), lambda i: (0, 0)),
                    pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, fw_pad), jnp.uint32),
-                   jax.ShapeDtypeStruct((capacity + 1, BLOCK_WORDS), jnp.uint16),
+                   jax.ShapeDtypeStruct((BLOCK_WORDS, capacity + 1), jnp.uint16),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, TILE), jnp.uint16),
                         pltpu.SMEM((4,), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x, x, eb_arr)
-    return bitflags[0, :p.flag_words], payload[:capacity], nnz[0, 0]
+    )(x, x, step)
+    return bitflags[0, :p.flag_words], payload[:, :capacity], nnz[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -303,7 +307,7 @@ def _make_encode_kernel(capacity: int, tiles_per_step: int):
         @pl.when(i == 0)
         def _():
             sm_ref[0] = 0
-            payload_ref[...] = jnp.zeros((capacity + 1, BLOCK_WORDS), jnp.uint16)
+            payload_ref[...] = jnp.zeros((BLOCK_WORDS, capacity + 1), jnp.uint16)
             nnz_ref[0, 0] = 0
 
         blocks, flags = _shuffle_tiles(codes_ref[...].reshape(-1),
@@ -332,7 +336,7 @@ def fused_shuffle_encode(codes_flat: jax.Array, *, capacity: int,
     if codes_flat.size % TILE:
         raise ValueError(f"size {codes_flat.size} not a multiple of TILE={TILE}")
     n_tiles = codes_flat.size // TILE
-    tps = _bsf.TILES_PER_BLOCK
+    tps = ENCODE_TILES
     padded = -(-n_tiles // tps) * tps
     x = jnp.pad(codes_flat.reshape(n_tiles, TILE), ((0, padded - n_tiles), (0, 0)))
     flag_words = n_tiles * FLAG_WORDS_PER_TILE
@@ -341,18 +345,18 @@ def fused_shuffle_encode(codes_flat: jax.Array, *, capacity: int,
         grid=(padded // tps,),
         in_specs=[pl.BlockSpec((tps, TILE), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((1, tps * FLAG_WORDS_PER_TILE), lambda i: (0, i)),
-                   pl.BlockSpec((capacity + 1, BLOCK_WORDS), lambda i: (0, 0)),
+                   pl.BlockSpec((BLOCK_WORDS, capacity + 1), lambda i: (0, 0)),
                    pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(
                        (1, padded * FLAG_WORDS_PER_TILE), jnp.uint32),
-                   jax.ShapeDtypeStruct((capacity + 1, BLOCK_WORDS), jnp.uint16),
+                   jax.ShapeDtypeStruct((BLOCK_WORDS, capacity + 1), jnp.uint16),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x)
-    return bitflags[0, :flag_words], payload[:capacity], nnz[0, 0]
+    return bitflags[0, :flag_words], payload[:, :capacity], nnz[0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -386,12 +390,12 @@ def kernel_spec(shape: tuple[int, ...], capacity_frac: float = 1.0,
             BlockDecl("halo", (1, *p.trailing), "float32",
                       index_map=lambda i: (max(min(i, clamp) * p.band - 1, 0),
                                            *zeros_trail)),
-            BlockDecl("eb", (1, 1), "float32", index_map=lambda i: (0, 0)),
+            BlockDecl("step", (1, 3), "float32", index_map=lambda i: (0, 0)),
         ),
         out_blocks=(
             BlockDecl("bitflags", (1, fw_pad), "uint32",
                       index_map=lambda i: (0, 0)),
-            BlockDecl("payload", (capacity + 1, BLOCK_WORDS), "uint16",
+            BlockDecl("payload", (BLOCK_WORDS, capacity + 1), "uint16",
                       index_map=lambda i: (0, 0)),
             BlockDecl("nnz", (1, 1), "int32", index_map=lambda i: (0, 0)),
         ),
@@ -405,7 +409,7 @@ def kernel_spec(shape: tuple[int, ...], capacity_frac: float = 1.0,
 
 @register_spec("fused_shuffle_encode")
 def _encode_spec(n_tiles: int, capacity_frac: float = 1.0) -> KernelSpec:
-    tps = _bsf.TILES_PER_BLOCK
+    tps = ENCODE_TILES
     padded = -(-max(n_tiles, 1) // tps) * tps
     capacity = _capacity_for(n_tiles * TILE, capacity_frac)
     return KernelSpec(
@@ -415,7 +419,7 @@ def _encode_spec(n_tiles: int, capacity_frac: float = 1.0) -> KernelSpec:
         out_blocks=(
             BlockDecl("bitflags", (1, tps * FLAG_WORDS_PER_TILE), "uint32",
                       index_map=lambda i: (0, i)),
-            BlockDecl("payload", (capacity + 1, BLOCK_WORDS), "uint16",
+            BlockDecl("payload", (BLOCK_WORDS, capacity + 1), "uint16",
                       index_map=lambda i: (0, 0)),
             BlockDecl("nnz", (1, 1), "int32", index_map=lambda i: (0, 0)),
         ),

@@ -115,7 +115,7 @@ def _make_decode_kernel(p: StreamPlan, capacity: int, code_mode: str,
         excl = (jnp.cumsum(fv_i, axis=1) - fv_i).reshape(nb)
         off = sm_ref[1] + excl
         in_cap = fv & (off < capacity)
-        rows = payload_ref[jnp.minimum(off, capacity - 1)]
+        rows = payload_ref[:, jnp.minimum(off, capacity - 1)].T
         blocks = jnp.where(in_cap[:, None], rows, jnp.uint16(0))
         codes = _unshuffle_tiles(blocks.reshape(wmax, TILE), wmax)
 
@@ -161,7 +161,7 @@ def fused_decompress(bitflags: jax.Array, payload: jax.Array, eb: jax.Array,
     the optional exact-outlier residual channel.
     """
     p = plan_stream(tuple(shape))
-    capacity = payload.shape[0]
+    capacity = payload.shape[1]
     wmax = p.wmax_decode
     # flag words the decoder may touch: every band opens at most wmax tiles
     need = (-(-p.bands * p.m // TILE) + wmax) * FLAG_WORDS_PER_TILE
@@ -172,7 +172,7 @@ def fused_decompress(bitflags: jax.Array, payload: jax.Array, eb: jax.Array,
     band_block = (p.band, *p.trailing)
     zeros_trail = (0,) * len(p.trailing)
     in_specs = [pl.BlockSpec((1, bf.shape[1]), lambda i: (0, 0)),
-                pl.BlockSpec((capacity, BLOCK_WORDS), lambda i: (0, 0)),
+                pl.BlockSpec((BLOCK_WORDS, capacity), lambda i: (0, 0)),
                 pl.BlockSpec((1, 1), lambda i: (0, 0))]
     args = [bf, payload, jnp.reshape(jnp.asarray(eb, jnp.float32), (1, 1))]
     if n_outliers:
@@ -191,7 +191,7 @@ def fused_decompress(bitflags: jax.Array, payload: jax.Array, eb: jax.Array,
         scratch_shapes=[pltpu.VMEM((1, TILE), jnp.uint16),
                         pltpu.VMEM(qcarry_shape, jnp.int32),
                         pltpu.SMEM((4,), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
@@ -218,7 +218,7 @@ def kernel_spec(shape: tuple[int, ...],
         in_blocks=(
             BlockDecl("bitflags", (1, max(need, 1)), "uint32",
                       index_map=lambda i: (0, 0)),
-            BlockDecl("payload", (capacity, BLOCK_WORDS), "uint16",
+            BlockDecl("payload", (BLOCK_WORDS, capacity), "uint16",
                       index_map=lambda i: (0, 0)),
             BlockDecl("eb", (1, 1), "float32", index_map=lambda i: (0, 0)),
         ),

@@ -1,6 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Interpret-mode fallback: on non-TPU backends (this container is CPU) the
+Interpret mode: on non-TPU backends the
 kernels execute through the Pallas interpreter, which runs the kernel body
 in Python/XLA for bit-exact validation against ref.py. On TPU the same
 pallas_call lowers to Mosaic. ``backend_interpret()`` is the one shared
@@ -9,12 +9,14 @@ instead of hardcoding ``interpret=True``.
 
 Two kernel flavors, selected by ``FZConfig.kernel_mode`` (see core/fz.py):
 
-  * ``"fused"`` (default): single-launch megakernels — the whole compress
-    pipeline in one pallas_call (fused_compress.py) and the whole decompress
-    pipeline in another (fused_decode.py); the code stream never touches HBM.
-  * ``"staged"``: the PR-3-era two-kernel path (lorenzo_quant, then
-    bitshuffle_flag with an XLA phase-2 epilogue) — retained as a second
-    oracle next to the pure-jnp reference.
+  * ``"fused"``: single-launch megakernels — the whole compress pipeline in
+    one pallas_call (fused_compress.py) and the whole decompress pipeline in
+    another (fused_decode.py); the code stream never touches HBM. Mosaic
+    does not compile them for the v5e yet, so a TPU never routes here
+    (``tune.dispatch.TPU_FUSED_MAX_ELEMS``).
+  * ``"staged"``: per-stage kernels (lorenzo_quant, then bitshuffle_flag
+    with an XLA phase-2 epilogue; bitunshuffle on the way back) — the path
+    a TPU runs at every size.
 
 Signature compatibility: the staged wrappers expose the same interfaces as
 the reference stages in repro.core so FZConfig swaps them in transparently
@@ -57,12 +59,9 @@ def backend_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_interpret = backend_interpret  # intra-module shorthand
-
-
 def backend_label() -> str:
     """Span/metric label for where the kernels execute."""
-    return "interpret" if _interpret() else "tpu"
+    return "interpret" if backend_interpret() else "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -71,20 +70,21 @@ def backend_label() -> str:
 
 def lorenzo_quantize(data: jax.Array, eb: jax.Array, *, code_mode: str = "sign_mag",
                      outlier_capacity: int = 0):
-    """Kernel-path dual-quantization (paper-faithful: saturating, no outliers).
+    """Kernel-path dual-quantization, same signature as
+    ``core.quant.dual_quantize``.
 
-    With outlier_capacity > 0 (strict-error-bound mode) the exact residual
-    side channel needs the unsaturated deltas, which the fused kernel by
-    design never materializes — quantization falls back to the reference
-    implementation (the shuffle/encode kernels, the hot 70+% of the pipeline
-    per paper Fig. 1, still run as kernels).
+    Paper mode (outlier_capacity == 0) saturates and forgets. Strict mode
+    (outlier_capacity > 0) has the kernel also write the int32 residuals,
+    which XLA compacts into the exact-outlier side channel.
     """
     with obs.span("fz.stage.quantize", backend=backend_label()):
         if outlier_capacity > 0:
-            return _quant.dual_quantize(data, eb, code_mode=code_mode,
-                                        outlier_capacity=outlier_capacity)
+            codes, resid = _lq.lorenzo_quant(
+                data, eb, code_mode=code_mode, with_residual=True,
+                interpret=backend_interpret())
+            return (codes, *_quant.collect_outliers(resid, outlier_capacity))
         codes = _lq.lorenzo_quant(data, eb, code_mode=code_mode,
-                                  interpret=_interpret())
+                                  interpret=backend_interpret())
         zero_i = jnp.zeros((0,), jnp.int32)
         return codes, zero_i, zero_i, jnp.int32(0)
 
@@ -99,25 +99,30 @@ def bitshuffle_flag_encode(codes_flat: jax.Array, *, capacity: int):
         raise ValueError(f"size {codes_flat.size} not a multiple of TILE={TILE}")
     with obs.span("fz.stage.shuffle_encode", backend=backend_label()):
         tiles = codes_flat.reshape(-1, TILE)
-        shuffled, byteflags = _bsf.bitshuffle_flag(tiles, interpret=_interpret())
+        shuffled, byteflags = _bsf.bitshuffle_flag(
+            tiles, interpret=backend_interpret())
         flags = byteflags.reshape(-1).astype(bool)
         return _enc.compact_blocks(
-            flags, shuffled.reshape(-1, _enc.BLOCK_WORDS), capacity=capacity)
+            flags, shuffled.reshape(_enc.BLOCK_WORDS, -1), capacity=capacity)
 
 
 @jax.jit
 def bitshuffle(codes_flat: jax.Array) -> jax.Array:
-    """Shuffle-only kernel path (flags discarded) for tests/benchmarks."""
-    shuffled, _ = _bsf.bitshuffle_flag(codes_flat.reshape(-1, TILE), interpret=_interpret())
-    return shuffled.reshape(-1)
+    """Shuffle-only kernel path (flags discarded) for tests/benchmarks:
+    flat codes -> flat shuffled words, as core.shuffle.bitshuffle."""
+    shuffled, _ = _bsf.bitshuffle_flag(codes_flat.reshape(-1, TILE),
+                                       interpret=backend_interpret())
+    return shuffled.reshape(_enc.BLOCK_WORDS, -1).T.reshape(-1)
 
 
 @jax.jit
-def bitunshuffle(words_flat: jax.Array) -> jax.Array:
-    """Inverse transform kernel, same signature as core.shuffle.bitunshuffle."""
+def bitunshuffle(words: jax.Array) -> jax.Array:
+    """Inverse transform kernel: word-major (8, n_blocks) shuffled words
+    (``core.encode.decode_blocks``) -> flat codes."""
     with obs.span("fz.stage.unshuffle", backend=backend_label()):
-        tiles = words_flat.reshape(-1, TILE)
-        return _bsf.bitunshuffle_tiles(tiles, interpret=_interpret()).reshape(-1)
+        tiles = words.reshape(_enc.BLOCK_WORDS, -1, _bsf.BLOCKS_PER_TILE)
+        return _bsf.bitunshuffle_tiles(
+            tiles, interpret=backend_interpret()).reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -129,26 +134,25 @@ def fused_compress_stages(data: jax.Array, eb: jax.Array, *,
                           outlier_capacity: int = 0):
     """One-launch compress: (bitflags, payload, nnz, oidx, oval, n_over).
 
-    Outlier routing is EXPLICIT here (not a silent fallback): the exact
-    residual side channel needs the unsaturated int32 deltas, and the fused
-    megakernel by design never materializes them (codes are born saturated
-    in VMEM). With ``outlier_capacity > 0`` the pipeline therefore routes
-    quantization through the reference implementation to harvest the
-    residuals and runs the fused shuffle+flag+compaction megakernel on the
-    resulting codes — still no shuffled-stream HBM round trip, and the
-    strict error bound is preserved (pinned in tests/test_kernels.py).
+    The exact residual side channel needs the unsaturated int32 deltas,
+    which the megakernel never materializes (codes are born saturated in
+    VMEM). With ``outlier_capacity > 0`` quantization therefore runs as the
+    staged quantization kernel, residuals included, and the fused
+    shuffle+flag+compaction megakernel takes its codes — still no
+    shuffled-stream HBM round trip, and the strict error bound is preserved
+    (pinned in tests/test_kernels.py).
     """
     with obs.span("fz.stage.fused_compress", backend=backend_label()):
         if outlier_capacity > 0:
-            codes, oidx, oval, n_over = _quant.dual_quantize(
+            codes, oidx, oval, n_over = lorenzo_quantize(
                 data, eb, code_mode=code_mode, outlier_capacity=outlier_capacity)
             flat = _shuffle.pad_to_tiles(codes.reshape(-1))
             bitflags, payload, nnz = _fc.fused_shuffle_encode(
-                flat, capacity=capacity, interpret=_interpret())
+                flat, capacity=capacity, interpret=backend_interpret())
             return bitflags, payload, nnz, oidx, oval, n_over
         bitflags, payload, nnz = _fc.fused_compress(
             data, eb, capacity=capacity, code_mode=code_mode,
-            interpret=_interpret())
+            interpret=backend_interpret())
         zero_i = jnp.zeros((0,), jnp.int32)
         return bitflags, payload, nnz, zero_i, zero_i, jnp.int32(0)
 
@@ -162,4 +166,4 @@ def fused_decompress(bitflags: jax.Array, payload: jax.Array, eb: jax.Array, *,
         return _fd.fused_decompress(
             bitflags, payload, eb, shape=tuple(shape), code_mode=code_mode,
             outlier_idx=outlier_idx, outlier_val=outlier_val,
-            interpret=_interpret())
+            interpret=backend_interpret())

@@ -20,7 +20,12 @@ inputs from the HLO text itself:
     (each device receives ~the full gathered array); all-reduce: 2x output
     (ring = reduce-scatter + all-gather); reduce-scatter: operand bytes
     (~full input transits each device); all-to-all / collective-permute:
-    output bytes. Start/done pairs counted once;
+    output bytes. Start/done pairs counted once, and so is an op that the
+    TPU compiler clones into the start/update/done computations of an async
+    collective fusion (same ``channel_id`` and shape whatever its layout's
+    memory space, tagged ``chain_id``;
+    XLA:CPU gives every shard_map collective channel 1, so the channel
+    alone is no key);
   * call-graph multipliers: while bodies/conditions multiply by the trip
     count recovered from the condition's ``compare(counter, constant)``;
     fusion/call computations inherit the caller's multiplier.
@@ -56,6 +61,8 @@ _ATTR_COND = re.compile(r"condition=%?([\w.\-]+)")
 _CONSTANT = re.compile(r"constant\((-?\d+)\)")
 _CONTRACT = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CHANNEL = re.compile(r"channel_id=(\d+)")
+_LAYOUT = re.compile(r"\{[^}]*\}")
 
 
 def _shape_dims(shape_str: str) -> list[tuple[str, list[int]]]:
@@ -350,6 +357,7 @@ def analyze(text: str, devices_per_pod: int | None = None,
     coll_detail: dict[str, float] = defaultdict(float)
     cross_pod_by_tag: dict[str, dict[str, float]] = defaultdict(
         lambda: defaultdict(float))
+    clones: set[tuple[str, str]] = set()
     for cname, c in comps.items():
         m_here = mult.get(cname, 0.0)
         if m_here == 0.0:
@@ -362,6 +370,13 @@ def analyze(text: str, devices_per_pod: int | None = None,
                 bytes_ += m_here * (opnd + _shape_bytes(op.out_shape))
             base = op.opcode[:-6] if op.opcode.endswith("-start") else op.opcode
             if base in COLLECTIVES:
+                ch = _CHANNEL.search(op.rest)
+                if ch and "chain_id=" in op.rest:
+                    # a clone's layout may name another memory space (S(1))
+                    key = (ch.group(1), _LAYOUT.sub("", op.out_shape))
+                    if key in clones:
+                        continue
+                    clones.add(key)
                 if base == "all-reduce":        # ring: RS + AG
                     b = 2 * _shape_bytes(op.out_shape)
                 elif base == "reduce-scatter":  # ~full input transits

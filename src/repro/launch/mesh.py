@@ -13,13 +13,12 @@ Axis roles:
 from __future__ import annotations
 
 import jax
-
-from repro.dist import compat
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    # all axes auto-partitioned; compat owns the jax-version split
-    return compat.make_mesh(shape, axes)
+    # all axes auto-partitioned (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -33,10 +33,12 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro import configs
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import zoo
     from repro.serve import Engine, KVCompressionConfig, PoolConfig, Request
     from repro.serve.engine import cache_bytes, compressed_cache_bytes
 
+    use_compile_cache()
     cfg = configs.get(args.arch, smoke=args.smoke)
     model = zoo.build(cfg)
     params = model.init(jax.random.key(0))

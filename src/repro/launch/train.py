@@ -46,6 +46,45 @@ def enable_overlap_scheduler_flags() -> None:
         os.environ["LIBTPU_INIT_ARGS"] = " ".join([cur, *missing]).strip()
 
 
+def build_trainer(arch: str, *, smoke: bool = False, layers: int | None = None,
+                  shape: str | None = None, seq: int = 128, batch: int = 8,
+                  steps: int = 50, microbatches: int = 1, pods: int = 1,
+                  model_parallel: int = 1, grad_compress=None,
+                  ckpt_dir: str | None = None, ckpt_codec: str = "raw"):
+    """The in-process training path behind the CLI: model, mesh over the
+    local devices, and a :class:`~repro.train.Trainer` ready to ``run``.
+
+    ``layers`` cuts the architecture's depth and keeps its published widths;
+    ``grad_compress`` is the gradient-exchange config (plain when ``None``).
+    Returns ``(trainer, cfg)``.
+    """
+    import dataclasses
+
+    from repro import configs
+    from repro.configs.base import SHAPES, ShapeConfig
+    from repro.data.tokens import TokenStream
+    from repro.dist.compressed_allreduce import GradCompressionConfig
+    from repro.launch.mesh import make_local_mesh
+    from repro.models import zoo
+    from repro.train import TrainConfig, Trainer
+
+    cfg = configs.get(arch, smoke=smoke)
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    model = zoo.build(cfg)
+    tshape = SHAPES[shape] if shape else ShapeConfig("local", seq, batch, "train")
+    mesh = make_local_mesh(model_parallel=model_parallel, pods=pods)
+    tcfg = TrainConfig(
+        microbatches=microbatches, total_steps=steps,
+        warmup_steps=max(steps // 10, 1),
+        grad_compress=grad_compress or GradCompressionConfig())
+    stream = TokenStream(vocab_size=cfg.vocab, seq_len=tshape.seq_len,
+                         global_batch=tshape.global_batch, seed=0)
+    trainer = Trainer(model, tshape, mesh, tcfg, stream=stream,
+                      ckpt_dir=ckpt_dir, ckpt_codec=ckpt_codec)
+    return trainer, cfg
+
+
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
@@ -75,37 +114,26 @@ def main() -> None:
     if args.overlap_reduce:
         enable_overlap_scheduler_flags()   # before jax initializes below
 
-    from repro import configs
-    from repro.configs.base import SHAPES, ShapeConfig
-    from repro.obs import cli as obs_cli
-    from repro.data.tokens import TokenStream
     from repro.dist.compressed_allreduce import GradCompressionConfig
-    from repro.launch.mesh import make_local_mesh
-    from repro.models import zoo
-    from repro.train import TrainConfig, Trainer
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.obs import cli as obs_cli
 
-    cfg = configs.get(args.arch, smoke=args.smoke)
-    model = zoo.build(cfg)
-    if args.shape:
-        shape = SHAPES[args.shape]
-    else:
-        shape = ShapeConfig("local", args.seq, args.batch, "train")
-    mesh = make_local_mesh(model_parallel=args.model_parallel, pods=args.pods)
-    tcfg = TrainConfig(
-        microbatches=args.microbatches, total_steps=args.steps,
-        warmup_steps=max(args.steps // 10, 1),
+    use_compile_cache()
+    trainer, cfg = build_trainer(
+        args.arch, smoke=args.smoke, shape=args.shape,
+        seq=args.seq, batch=args.batch, steps=args.steps,
+        microbatches=args.microbatches, pods=args.pods,
+        model_parallel=args.model_parallel,
         grad_compress=GradCompressionConfig(
             enabled=args.compressed_grads or args.overlap_reduce,
-            overlap=args.overlap_reduce, bucket_bytes=args.bucket_bytes))
-    stream = TokenStream(vocab_size=cfg.vocab, seq_len=shape.seq_len,
-                         global_batch=shape.global_batch, seed=0)
-    trainer = Trainer(model, shape, mesh, tcfg, stream=stream,
-                      ckpt_dir=args.ckpt_dir, ckpt_codec=args.ckpt_codec)
-    reduce_mode = ("bucketed-overlap" if args.overlap_reduce else
-                   "barrier" if tcfg.grad_compress.enabled else "exact")
-    print(f"{cfg.arch_id}: {model.param_count()/1e6:.1f}M params, "
-          f"mesh={dict(mesh.shape)}, reduce={reduce_mode}, "
-          f"resume_step={trainer.step}")
+            overlap=args.overlap_reduce, bucket_bytes=args.bucket_bytes),
+        ckpt_dir=args.ckpt_dir, ckpt_codec=args.ckpt_codec)
+    gc = trainer.tcfg.grad_compress
+    reduce_mode = ("bucketed-overlap" if gc.overlap else
+                   "barrier" if gc.enabled else "exact")
+    print(f"{cfg.arch_id}: {trainer.model.param_count()/1e6:.1f}M params, "
+          f"mesh={dict(trainer.mesh.shape)}, "
+          f"reduce={reduce_mode}, resume_step={trainer.step}")
     obs_cli.start(args)
     hist = trainer.run(args.steps - trainer.step)
     for m in hist[:: max(len(hist) // 10, 1)]:

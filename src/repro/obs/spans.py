@@ -14,7 +14,7 @@
     ``jax.profiler`` traces captured via ``--profile-dir`` on hardware).
 
 jit discipline (load-bearing; pinned in tests/test_obs.py): a span entered
-while a trace is in progress (``jax.core.trace_state_clean()`` is False)
+while a trace is in progress (``jax.core.trace_ctx.is_top_level()`` is False)
 records **no runtime timing** — it contributes only the named_scope metadata
 plus a single ``cat="jit-trace"`` ring event measuring how long *tracing*
 that region took. Nothing is staged into the traced program: no ops, no
@@ -119,7 +119,7 @@ class span:
         if not _reg.enabled():
             self._frames.append(None)
             return self
-        eager = jax.core.trace_state_clean()
+        eager = jax.core.trace_ctx.is_top_level()
         stack = _stack.get()
         token = _stack.set(stack + (self.name,))
         scope = jax.named_scope(self.name)

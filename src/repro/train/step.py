@@ -40,11 +40,14 @@ def _named(mesh, spec_tree_, abstract_tree):
     return shd.tree_shardings(spec_tree_, abstract_tree, mesh)
 
 
-def _install_act_sharder(mesh) -> None:
-    """Route model-side nn.shard_act calls to this mesh (trace-time global)."""
+def _install_act_sharder(mesh, exclude: tuple[str, ...] = ()) -> None:
+    """Route model-side nn.shard_act calls to this mesh (trace-time global).
+    Mesh axes in ``exclude`` are left to an enclosing vmap's
+    ``spmd_axis_name``. Like the grad tap, each train step installs its own
+    at the top of its body."""
 
     def sharder(x, logical):
-        spec = shd.resolve_spec(tuple(logical), x.shape, mesh)
+        spec = shd.resolve_spec(tuple(logical), x.shape, mesh, exclude)
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
     nn.set_act_sharder(sharder)
@@ -133,6 +136,13 @@ def build_train_step(model: zoo.Model, shape: ShapeConfig, mesh, tcfg: TrainConf
 
         def step(params, opt_state, err_state, step_idx, batch):
             _install_grad_tap(overlap)   # runs at trace time, see helper
+            # each pod's activations stay on that pod: the vmapped dim is
+            # sharded over 'pod' (spmd_axis_name) and the model's own
+            # constraints use the in-pod axes only. Left to the act sharder,
+            # 'dp' would spread one pod's batch over both pods, and every
+            # per-pod gradient would be all-reduced across pods in full
+            # before its compressed hop.
+            _install_act_sharder(mesh, exclude=("pod",))
 
             def split(x):
                 b = x.shape[0]
@@ -144,7 +154,8 @@ def build_train_step(model: zoo.Model, shape: ShapeConfig, mesh, tcfg: TrainConf
                 l, g = _loss_and_grads(model, p, b, tcfg.microbatches)
                 return l, g
 
-            losses, grads_stacked = jax.vmap(pod_loss, in_axes=(None, 0))(params, pods_batch)
+            losses, grads_stacked = jax.vmap(
+                pod_loss, in_axes=(None, 0), spmd_axis_name="pod")(params, pods_batch)
             if overlap:
                 grads, err_state = bkt.reduce_stacked_bucketed(
                     grads_stacked, err_state, tcfg.grad_compress, mesh, plan=plan)
@@ -160,6 +171,7 @@ def build_train_step(model: zoo.Model, shape: ShapeConfig, mesh, tcfg: TrainConf
     else:
         def step(params, opt_state, err_state, step_idx, batch):
             _install_grad_tap(False)     # runs at trace time, see helper
+            _install_act_sharder(mesh)
             loss, grads = _loss_and_grads(model, params, batch, tcfg.microbatches)
             p, o, m = _finish(loss, grads, params, opt_state, step_idx)
             return p, o, err_state, m

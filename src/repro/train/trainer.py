@@ -77,13 +77,21 @@ class Trainer:
         self.watchdog = StragglerWatchdog()
         self.step_fn, self.info = build_train_step(model, shape, mesh, tcfg)
 
-        params = model.init(jax.random.key(seed))
-        opt = adamw_init(params)
-        self.params = jax.device_put(params, self.info["params"])
-        self.opt = jax.device_put(opt, self.info["opt"])
+        # state is born in its shardings: built on one device and then
+        # placed, params + AdamW + error feedback of a model at published
+        # widths overflow that device's HBM
+        self.params = jax.jit(model.init, out_shardings=self.info["params"])(
+            jax.random.key(seed))
+        self.opt = jax.jit(adamw_init, out_shardings=self.info["opt"])(
+            self.params)
         grads_abs = jax.tree.map(
             lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), self.params)
-        self.err = self._place_err(self.info["make_err_state"](grads_abs))
+        make_err = lambda: self.info["make_err_state"](grads_abs)
+        if self.info["err_shardings"] is None:
+            self.err = make_err()
+        else:
+            self.err = jax.jit(make_err, out_shardings=self.info[
+                "err_shardings"](grads_abs))()
         self.step = 0
         self.history: list[dict] = []
         if ckpt_dir is not None and ckpt.latest_step(ckpt_dir) is not None:

@@ -7,24 +7,23 @@ persistent cache is loaded once per process (and re-read only when the
 tuner writes a new winner via :func:`invalidate_memo`), and resolutions are
 memoized per ``(op, bucket, dtype)``.
 
-Backend-aware fallback ordering (the bugfix half, see also the
-``core/fz.py`` module docstring): when no tuning-cache entry exists for a
-workload, "auto" does **not** blindly take the fused megakernels —
-``BENCH_ci.json`` measures fused compress ~4x *slower* than staged under
-the Pallas interpreter (the non-TPU execution mode), because the
-interpreter executes the megakernel's sequential grid in Python. The static
-ordering is therefore per-backend:
+Backend-aware routing (see also the ``core/fz.py`` module docstring):
 
-  * ``interpret`` / ``gpu`` (kernels interpret-executed today): staged
-    before fused, reference last;
-  * ``tpu``: fused first (single-launch, no HBM round-trip for the code
-    stream — the paper's §3.5 fusion claim), staged, reference.
+  * ``tpu``: a fixed rule on what the code can observe, the element count
+    (:func:`tpu_fz_impl`). The tuning cache is not consulted and the jnp
+    reference is never chosen: on the chip a kernel request runs kernels,
+    or fails loudly.
+  * ``interpret`` / ``gpu`` (kernels interpret-executed today): the cached
+    winner if any, else staged before fused, reference last —
+    ``BENCH_ci.json`` measures fused compress ~4x *slower* than staged
+    under the Pallas interpreter, which executes the megakernel's
+    sequential grid in Python.
 
 Untuned ``decode_attention`` keeps the kernel path — that request is
 explicit (``use_kernels=True``) and kernel-vs-jnp parity is pinned; the
 cache only *overrides* it where the jnp oracle measures faster.
 
-Counters (gated on ``jax.core.trace_state_clean()`` so retraces are never
+Counters (gated on ``jax.core.trace_ctx.is_top_level()`` so retraces are never
 tallied): ``tune_cache{result=hit|miss, site=dispatch}`` and
 ``tune_selected{op=..., impl=..., site=dispatch}``.
 """
@@ -38,12 +37,21 @@ from . import registry
 from .cache import TuneCache, cache_key, shape_bucket
 
 # per-backend static ordering when no cache entry exists (most-preferred
-# first); "gpu" mirrors "interpret" until real Triton lowering is measured
+# first); "gpu" mirrors "interpret" until real Triton lowering is measured.
+# A TPU is routed by tpu_fz_impl instead.
 FZ_FALLBACK = {
     "interpret": ("staged", "fused", "reference"),
     "gpu": ("staged", "fused", "reference"),
-    "tpu": ("fused", "staged", "reference"),
 }
+
+# Largest element count the fused megakernels are routed to on a TPU. It is
+# 0 because the v5e compiler refuses both megakernels at every size tried,
+# 4096 elements up to 512^3: "Cannot store scalars to VMEM"
+# (fused_compress, fused_shuffle_encode) and an unimplemented in-kernel
+# `cumsum` (fused_decode). tests/test_tpu_compile.py pins those refusals and
+# the staged kernels' compiles at the SDRBench shapes, so raising this limit
+# starts with a megakernel that compiles there.
+TPU_FUSED_MAX_ELEMS = 0
 
 _cache: TuneCache | None = None
 _memo: dict[tuple[str, int, str], tuple[str, str]] = {}
@@ -93,7 +101,7 @@ def invalidate_memo() -> None:
 
 
 def _count(result: str, op: str, impl: str) -> None:
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         return
     obs.counter("tune_cache", result=result, site="dispatch").inc()
     obs.counter("tune_selected", op=op, impl=impl, site="dispatch").inc()
@@ -114,9 +122,19 @@ def _resolve(op: str, n: int, dtype: str, fallback_impl: str) -> str:
     return impl
 
 
-def fz_fallback_mode(b: str | None = None) -> str:
-    """First *kernel* choice of the static ordering ("staged" or "fused")."""
-    for impl in FZ_FALLBACK.get(b or backend(), FZ_FALLBACK["interpret"]):
+def tpu_fz_impl(n: int) -> str:
+    """The TPU routing rule for an FZ op over ``n`` elements."""
+    return "fused" if n <= TPU_FUSED_MAX_ELEMS else "staged"
+
+
+def fz_fallback_mode(n: int) -> str:
+    """Kernel path for an untuned "auto" config over ``n`` elements
+    ("staged" or "fused"): the TPU rule, else the first kernel of the
+    backend's static ordering."""
+    b = backend()
+    if b == "tpu":
+        return tpu_fz_impl(n)
+    for impl in FZ_FALLBACK.get(b, FZ_FALLBACK["interpret"]):
         if impl != "reference":
             return impl
     return "staged"
@@ -124,10 +142,18 @@ def fz_fallback_mode(b: str | None = None) -> str:
 
 def resolve_fz(direction: str, n: int, dtype: str) -> str:
     """Winning impl for ``fz.compress``/``fz.decompress`` at this workload:
-    ``"reference" | "staged" | "fused"``. ``direction`` is "compress" or
-    "decompress"."""
+    ``"reference" | "staged" | "fused"`` (never ``"reference"`` on a TPU).
+    ``direction`` is "compress" or "decompress"."""
     op = f"fz.{direction}"
     b = backend()
+    if b == "tpu":
+        impl = tpu_fz_impl(n)
+        if not any(c.impl == impl for c in registry.candidates(op, backend=b)):
+            raise RuntimeError(
+                f"no {impl!r} kernel registered for {op} on TPU; a kernel "
+                f"request on the chip never falls back to the jnp reference")
+        _count("rule", op, impl)
+        return impl
     fallback = next(
         (impl for impl in FZ_FALLBACK.get(b, FZ_FALLBACK["interpret"])
          if any(c.impl == impl for c in registry.candidates(op, backend=b))),
@@ -138,6 +164,9 @@ def resolve_fz(direction: str, n: int, dtype: str) -> str:
 def decode_attention_impl(n: int, dtype: str) -> str:
     """Winning impl for decode attention at a per-sequence cache of ``n``
     elements: ``"kernel" | "jnp"``. Untuned default stays "kernel" — the
-    caller asked for kernels and parity is pinned; the cache only overrides
-    where the oracle measured faster."""
+    caller asked for kernels and parity is pinned; off a TPU the cache only
+    overrides where the oracle measured faster. On a TPU it is always the
+    kernel: a kernel request on the chip never runs the jnp oracle."""
+    if backend() == "tpu":
+        return "kernel"
     return _resolve("decode_attention", n, str(dtype), "kernel")

@@ -9,6 +9,7 @@ import pytest
 from repro.analysis import carry, jitlint, report, resources
 from repro.analysis.kernelspec import (BlockDecl, KernelSpec, ScratchDecl,
                                        probe_index_map, spec_builders)
+from repro.core import quant
 from repro.kernels import lorenzo_quant as lq
 from repro.kernels import ref
 
@@ -373,6 +374,7 @@ def test_probe_index_map_classifies_axes():
 @pytest.mark.parametrize("shape", [(4096,), (33, 100)])
 def test_lorenzo_quant_bf16_matches_f32_reference(shape):
     x = jnp.asarray(RNG.standard_normal(shape), jnp.bfloat16)
-    k = lq.lorenzo_quant(x, jnp.float32(1e-2), interpret=True)
-    r = ref.lorenzo_quant_ref(x, jnp.float32(1e-2))
+    eb = quant.snap_eb(jnp.float32(1e-2))
+    k = lq.lorenzo_quant(x, eb, interpret=True)
+    r = ref.lorenzo_quant_ref(x, eb)
     np.testing.assert_array_equal(np.asarray(k), np.asarray(r))

@@ -172,7 +172,7 @@ def test_grad_boundary_is_bit_exact_identity():
     """Arming the taps changes neither the loss nor any gradient bit: the
     boundary is a custom_vjp identity whose backward only pins scheduling
     (optimization_barrier), under plain grad and under vmap(grad) — the
-    step builder's pod vmap relies on the compat batching rule."""
+    step builder's pod vmap relies on the barrier's own batching rule."""
     from repro import configs
     from repro.models import nn, zoo
 

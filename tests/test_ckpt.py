@@ -56,7 +56,7 @@ def test_fz_codec_error_bounded(tmp_path):
     restored, _ = ckpt.restore(str(tmp_path), t)
     rng_ = big.max() - big.min()
     err = np.abs(np.asarray(restored["big"]) - big).max()
-    # 1.01x + ulp slack: f32 divide/rint/multiply rounding at q ~ 5e4
+    # 1.01x + ulp slack: f32 multiply/rint/multiply rounding at q ~ 5e4
     assert err <= 1e-5 * rng_ * 1.01 + rng_ * 2e-7, err
     np.testing.assert_array_equal(np.asarray(restored["small"]), np.ones(8, np.float32))
     rep = ckpt.compression_report(str(tmp_path), 1)

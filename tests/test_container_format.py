@@ -49,7 +49,7 @@ def test_deserialized_container_is_leaf_identical():
     cfg = fz.FZConfig(eb=1e-3, eb_mode="rel")
     comp = fz.compress(f, cfg)
     raw = fz.to_bytes(comp, cfg, entropy=True)
-    back, _ = fz.from_bytes(raw, capacity=int(comp.payload.shape[0]),
+    back, _ = fz.from_bytes(raw, capacity=int(comp.payload.shape[1]),
                             outlier_capacity=int(comp.outlier_idx.shape[0]))
     for a, b in zip(jax.tree.leaves(comp), jax.tree.leaves(back)):
         assert np.array_equal(np.asarray(a), np.asarray(b))

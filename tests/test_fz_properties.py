@@ -297,6 +297,34 @@ def test_encoder_roundtrip_exact_seeded(seed, density):
     check_encoder_roundtrip_exact(seed, density)
 
 
+@pytest.mark.parametrize("use_kernels", [False, True], ids=["ref", "kernels"])
+@pytest.mark.parametrize("kind,dims,eb", [("smooth", (40, 50, 60), 1e-3),
+                                          ("turbulent", (64, 64, 16), 1e-4),
+                                          ("smooth", (300, 700), 1e-3),
+                                          ("particle", (50_000,), 1e-4)])
+def test_strict_bound_holds_without_rounding_allowance(kind, dims, eb,
+                                                       use_kernels):
+    """With the snapped bound and |q| < 2**16 the strict mode holds
+    max_abs_err <= eb_abs exactly, on the reference and the kernel path."""
+    from repro.data import make_field
+    x = jnp.asarray(make_field(kind, dims, seed=0))
+    cfg = fz.FZConfig(eb=eb, eb_mode="rel", exact_outliers=True,
+                      use_kernels=use_kernels, kernel_mode="staged")
+    rec, c = fz.roundtrip(x, cfg)
+    assert float(metrics.max_abs_err(x, rec)) <= float(c.eb_abs)
+
+
+def test_inv_two_eb_is_the_correctly_rounded_reciprocal():
+    """The integer long division equals IEEE float32 1/(2eb) bit for bit,
+    over log-uniform bounds and every power of two in range."""
+    rng = np.random.default_rng(0)
+    eb = np.concatenate([10.0 ** rng.uniform(-30, 30, 20000),
+                         2.0 ** np.arange(-99, 100)]).astype(np.float32)
+    got = np.asarray(jax.jit(quant.inv_two_eb)(jnp.asarray(eb)))
+    want = np.float32(1) / (np.float32(2) * eb)
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+
+
 @pytest.mark.parametrize("seed", range(3))
 def test_lorenzo_inverse_exact_seeded(seed):
     check_lorenzo_inverse_exact(seed)

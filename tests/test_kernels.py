@@ -16,6 +16,11 @@ from repro.launch import hlo_cost
 RNG = np.random.default_rng(42)
 
 
+def _eb(v):
+    """A bound as the compressor uses it: snapped (``quant.snap_eb``)."""
+    return quant.snap_eb(jnp.float32(v))
+
+
 @pytest.mark.parametrize("n_tiles", [1, 2, 8, 9, 17])
 def test_bitshuffle_flag_matches_oracle(n_tiles):
     codes = jnp.asarray(RNG.integers(0, 1 << 16, size=(n_tiles, ref.TILE), dtype=np.uint16))
@@ -45,25 +50,43 @@ def test_unshuffle_matches_reference_oracle():
 @pytest.mark.parametrize("code_mode", ["sign_mag", "zigzag"])
 def test_lorenzo_quant_matches_oracle(shape, code_mode):
     x = jnp.asarray(RNG.standard_normal(shape).astype(np.float32))
-    k = lq.lorenzo_quant(x, jnp.float32(1e-3), code_mode=code_mode, interpret=True)
-    r = ref.lorenzo_quant_ref(x, jnp.float32(1e-3), code_mode=code_mode)
+    k = lq.lorenzo_quant(x, _eb(1e-3), code_mode=code_mode, interpret=True)
+    r = ref.lorenzo_quant_ref(x, _eb(1e-3), code_mode=code_mode)
     np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
 
 
 @pytest.mark.parametrize("eb", [1e-2, 1e-4, 3.7e-3])
 def test_lorenzo_quant_eb_sweep(eb):
     x = jnp.asarray(np.cumsum(RNG.standard_normal((50, 70)), axis=0).astype(np.float32))
-    k = lq.lorenzo_quant(x, jnp.float32(eb), interpret=True)
-    r = ref.lorenzo_quant_ref(x, jnp.float32(eb))
+    k = lq.lorenzo_quant(x, _eb(eb), interpret=True)
+    r = ref.lorenzo_quant_ref(x, _eb(eb))
     np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
 
 
 def test_saturation_on_rough_data():
     """Kernel saturates exactly like the reference on outlier-heavy data."""
     x = jnp.asarray(RNG.standard_normal((100, 100)).astype(np.float32) * 1e4)
-    k = lq.lorenzo_quant(x, jnp.float32(1e-4), interpret=True)
-    r = ref.lorenzo_quant_ref(x, jnp.float32(1e-4))
+    k = lq.lorenzo_quant(x, _eb(1e-4), interpret=True)
+    r = ref.lorenzo_quant_ref(x, _eb(1e-4))
     np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+
+
+@pytest.mark.parametrize("code_mode", ["sign_mag", "zigzag"])
+@pytest.mark.parametrize("shape", [(5000,), (40, 300), (9, 20, 130)],
+                         ids=["1d", "2d", "3d"])
+def test_residual_output_matches_reference_outliers(shape, code_mode):
+    """The kernel's strict-mode residuals, compacted, are the reference's
+    exact-outlier channel, on data rough enough to saturate often."""
+    x = jnp.asarray(RNG.standard_normal(shape).astype(np.float32) * 1e4)
+    eb = _eb(1e-2)
+    codes, resid = lq.lorenzo_quant(x, eb, code_mode=code_mode,
+                                    with_residual=True, interpret=True)
+    K = x.size
+    want = quant.dual_quantize(x, eb, code_mode=code_mode, outlier_capacity=K)
+    got = (codes, *quant.collect_outliers(resid, K))
+    assert int(want[3]) > 0
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_fz_kernel_path_bit_identical_to_reference():
@@ -80,9 +103,9 @@ def test_fz_kernel_path_bit_identical_to_reference():
 
 @pytest.mark.parametrize("kernel_mode", ["staged", "fused"])
 def test_fz_kernel_hybrid_strict_mode(kernel_mode):
-    """use_kernels + exact_outliers: quantization routes through the
-    reference (documented in ops.lorenzo_quantize / fused_compress_stages),
-    the rest stays kernels, and the strict bound holds."""
+    """use_kernels + exact_outliers: the quantization kernel also writes
+    the residuals (ops.lorenzo_quantize / fused_compress_stages), every
+    stage stays a kernel, and the strict bound holds."""
     x = jnp.asarray(RNG.standard_normal((64, 200)).astype(np.float32) * 50)
     cfg = fz.FZConfig(eb=1e-4, use_kernels=True, kernel_mode=kernel_mode,
                       exact_outliers=True, outlier_frac=1.0)
@@ -250,7 +273,7 @@ def _ref_compress(x, eb, code_mode, capacity):
 def test_fused_compress_matches_composed_reference(shape, code_mode):
     x = jnp.asarray(np.cumsum(RNG.standard_normal(shape), axis=0)
                     .astype(np.float32) * 0.3)
-    eb = jnp.float32(1e-3)
+    eb = _eb(1e-3)
     cap = fc.plan_stream(shape).padded_n // enc.BLOCK_WORDS
     bf_r, pl_r, nnz_r = _ref_compress(x, eb, code_mode, cap)
     bf_k, pl_k, nnz_k = fc.fused_compress(x, eb, capacity=cap,
@@ -263,7 +286,7 @@ def test_fused_compress_matches_composed_reference(shape, code_mode):
 def test_fused_compress_bounded_capacity_drops_like_reference():
     x = jnp.asarray(np.cumsum(RNG.standard_normal(20_000))
                     .astype(np.float32) * 0.3)
-    eb = jnp.float32(1e-4)
+    eb = _eb(1e-4)
     bf_r, pl_r, nnz_r = _ref_compress(x, eb, "sign_mag", 100)
     bf_k, pl_k, nnz_k = fc.fused_compress(x, eb, capacity=100, interpret=True)
     np.testing.assert_array_equal(np.asarray(bf_k), np.asarray(bf_r))
@@ -288,7 +311,7 @@ def test_fused_shuffle_encode_matches_core_encode():
 def test_fused_decompress_matches_composed_reference(shape):
     x = jnp.asarray(np.cumsum(RNG.standard_normal(shape), axis=0)
                     .astype(np.float32) * 0.3)
-    eb = jnp.float32(1e-3)
+    eb = _eb(1e-3)
     cap = fc.plan_stream(shape).padded_n // enc.BLOCK_WORDS
     bf, pld, _ = fc.fused_compress(x, eb, capacity=cap, interpret=True)
     words = enc.decode(bf, pld, n_blocks=fz.FZConfig.n_blocks(x.size))
@@ -303,7 +326,7 @@ def test_fused_decompress_applies_outlier_residuals_in_kernel():
     spikes = (RNG.random((120, 170)) < 0.01) * \
         RNG.standard_normal((120, 170)).astype(np.float32) * 100.0
     x = jnp.asarray(base + spikes)
-    eb = jnp.float32(1e-5)
+    eb = _eb(1e-5)
     K = x.size // 8
     codes, oidx, oval, n_over = quant.dual_quantize(x, eb, outlier_capacity=K)
     assert int(n_over) > 0

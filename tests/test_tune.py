@@ -183,12 +183,54 @@ def test_fallback_ordering_interpret(tmp_cache):
     """No cache entry: interpret-class backends must prefer staged over
     fused (the measured 4x fused-compress interpreter regression)."""
     assert dispatch.backend() == "interpret"   # CI runs on CPU
-    assert dispatch.fz_fallback_mode() == "staged"
+    assert dispatch.fz_fallback_mode(4096) == "staged"
     assert tune.resolve_fz("compress", 4096, "float32") == "staged"
     assert tune.resolve_fz("decompress", 4096, "float32") == "staged"
     # untuned decode attention honors the explicit kernel request
     assert tune.decode_attention_impl(4096, "bfloat16") == "kernel"
-    assert dispatch.FZ_FALLBACK["tpu"][0] == "fused"
+    # a TPU is routed by the size rule, not by this ordering
+    assert "tpu" not in dispatch.FZ_FALLBACK
+
+
+def test_tpu_dispatch_never_yields_reference(tmp_cache, monkeypatch):
+    """On a TPU the size rule decides, whatever the tuning cache says: a
+    cached "reference" winner never sends a kernel request to the jnp path."""
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    for direction in ("compress", "decompress"):
+        tmp_cache.put(tcache.cache_key("tpu", f"fz.{direction}", 4096,
+                                       "float32", dispatch.arch()),
+                      {"impl": "reference"})
+    tmp_cache.put(tcache.cache_key("tpu", "decode_attention", 4096,
+                                   "bfloat16", dispatch.arch()),
+                  {"impl": "jnp"})
+    dispatch.invalidate_memo()
+    assert tune.decode_attention_impl(4096, "bfloat16") == "kernel"
+    cfg = fz.FZConfig(eb=1e-3, use_kernels=True, exact_outliers=False)
+    for n in (1, 4096, 512 ** 3):
+        for direction in ("compress", "decompress"):
+            assert tune.resolve_fz(direction, n, "float32") == "staged"
+            resolved = fz._resolved(cfg, direction, n, "float32")
+            assert resolved.use_kernels and resolved.kernel_mode == "staged"
+        assert dispatch.fz_fallback_mode(n) == "staged"
+
+
+def test_tpu_dispatch_picks_fused_only_under_the_limit(monkeypatch):
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "TPU_FUSED_MAX_ELEMS", 1 << 16)
+    for direction in ("compress", "decompress"):
+        assert tune.resolve_fz(direction, 1 << 16, "float32") == "fused"
+        assert tune.resolve_fz(direction, (1 << 16) + 1, "float32") == "staged"
+    assert dispatch.fz_fallback_mode(4096) == "fused"
+    assert dispatch.fz_fallback_mode(1 << 20) == "staged"
+
+
+def test_tpu_dispatch_without_a_kernel_is_an_error(monkeypatch):
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    only_ref = [c for c in registry.candidates("fz.compress")
+                if c.impl == "reference"]
+    monkeypatch.setattr(registry, "candidates", lambda op, backend=None: only_ref)
+    with pytest.raises(RuntimeError, match="never falls back"):
+        tune.resolve_fz("compress", 4096, "float32")
 
 
 def test_cached_winner_overrides_fallback(tmp_cache):
@@ -232,10 +274,11 @@ def test_auto_path_bit_identical_to_reference(tmp_cache):
 
 
 def test_budget_skip_vmem_overflow():
-    """analysis integration: the fused megakernel candidates overflow VMEM
-    at the 1M-element reduce-bucket point (the committed baseline findings)
-    and must be skipped, not measured; staged stays eligible."""
-    ctx = {"n": 1 << 20, "dtype": "float32"}
+    """analysis integration: the fused megakernel candidates overflow the
+    modelled VMEM budget at 4M elements (their capacity-sized payload is
+    VMEM-resident) and must be skipped, not measured; staged stays
+    eligible."""
+    ctx = {"n": 1 << 22, "dtype": "float32"}
     cands = {c.impl: c for c in registry.candidates("fz.compress")}
     why = tuner._budget_skip(cands["fused"], ctx)
     assert why is not None and "vmem-overflow" in why
@@ -262,7 +305,7 @@ def test_tuner_records_skips_in_entry(tmp_cache, fake_op):
 def _overflow_spec():
     import repro.kernels  # noqa: F401  -- registers the spec builders
     from repro.analysis.kernelspec import spec_builders
-    return spec_builders()["fused_compress"](shape=(1 << 20,),
+    return spec_builders()["fused_compress"](shape=(1 << 22,),
                                              dtype="float32",
                                              capacity_frac=1.0)
 
