@@ -1,0 +1,6 @@
+"""Share of the time charged to compress in which no operation ran on the
+device, from the traced window."""
+
+
+def read(ctx):
+    return ctx.idle_share("compress")
