@@ -22,6 +22,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 BLOCK_WORDS = 8          # u16 words per zero-detection block (16 bytes)
 BLOCK_BYTES = 2 * BLOCK_WORDS
 FLAGS_PER_WORD = 32      # bit flags packed per u32
@@ -97,17 +99,18 @@ def compact_blocks(flags: jax.Array, words: jax.Array, *, capacity: int):
     megakernel (kernels/fused_compress.py) replaces this wholesale with an
     in-kernel running-offset scatter; this stays as its oracle.
     """
-    nnz = jnp.sum(flags, dtype=jnp.int32)
-    # src[k] = index of the k-th flagged block (0 past nnz), as
-    # jnp.nonzero(flags, size=capacity, fill_value=0)
-    n = flags.size
-    dst = jnp.where(flags, exclusive_cumsum(flags.astype(jnp.int32)), capacity)
-    src = jnp.zeros((capacity,), jnp.int32).at[dst].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop")
-    payload = words[:, src]
-    # slots past nnz replicate block 0; zero them so payload is deterministic
-    payload = jnp.where(jnp.arange(capacity)[None, :] < nnz, payload, 0)
-    return pack_bitflags(flags), payload.astype(jnp.uint16), nnz
+    with obs.span("fz.stage.compact_blocks"):
+        nnz = jnp.sum(flags, dtype=jnp.int32)
+        # src[k] = index of the k-th flagged block (0 past nnz), as
+        # jnp.nonzero(flags, size=capacity, fill_value=0)
+        n = flags.size
+        dst = jnp.where(flags, exclusive_cumsum(flags.astype(jnp.int32)), capacity)
+        src = jnp.zeros((capacity,), jnp.int32).at[dst].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
+        payload = words[:, src]
+        # slots past nnz replicate block 0; zero them so payload is deterministic
+        payload = jnp.where(jnp.arange(capacity)[None, :] < nnz, payload, 0)
+        return pack_bitflags(flags), payload.astype(jnp.uint16), nnz
 
 
 @partial(jax.jit, static_argnames=("capacity",))

@@ -43,11 +43,19 @@ is entered, so every jit cache key is a concrete resolved config — a later
 cache update can never leave a stale "auto" trace behind.
 
 Telemetry: the public entry points are thin eager wrappers over the jitted
-pipelines. When called eagerly they bump ``fz_dispatches{op=...}`` counters
-and compressed-stream size histograms in :mod:`repro.obs` and open an
-``fz.<op>`` span; when reached from inside an enclosing trace they fall
-straight through to the jitted inner (a trace is not a dispatch — counting
-there would tally compilations, not work). The batched page entry points
+pipelines. When called eagerly each opens an ``fz.<op>`` span around all of
+its host work (config resolution, the jit dispatch, the
+``fz_dispatches{op=...,path=...}`` counter in :mod:`repro.obs`); when
+reached from inside an enclosing trace they fall straight through to the
+jitted inner (a trace is not a dispatch — counting there would tally
+compilations, not work). Inside the staged and reference programs every
+operation sits under exactly one innermost ``fz.stage.<name>`` scope, which
+lands in the compiled program's op metadata and nowhere else: compress runs
+``resolve_eb``, ``quantize`` (holding ``collect_outliers``) and
+``shuffle_encode`` (holding ``compact_blocks``); decompress runs
+``decode_blocks``, ``unshuffle`` and ``dequantize``. :func:`lowered` gives
+the program a wrapper dispatches, so a profile's device ops can be joined to
+their stages. The batched page entry points
 (``compress_batch_with_eb`` / ``decompress_batch``) live here for the same
 reason: one vmapped launch is one dispatch, and keeping the counting next to
 the launch is what lets the kvpool's ``decompress_dispatches`` stat and the
@@ -199,22 +207,16 @@ def _stages(cfg: FZConfig):
 
     The fused megakernel path doesn't decompose into these three stages —
     ``_compress_core`` / ``decompress`` route it wholesale via ``_fused``.
+    The ``fz.stage.*`` scopes are opened by the callers, for either choice.
     """
     if cfg.use_kernels:
         from repro.kernels import ops as kops
         return kops.lorenzo_quantize, kops.bitshuffle_flag_encode, kops.bitunshuffle
-    def ref_quant(data, eb, *, code_mode, outlier_capacity):
-        with obs.span("fz.stage.quantize", backend="reference"):
-            return quant.dual_quantize(data, eb, code_mode=code_mode,
-                                       outlier_capacity=outlier_capacity)
     def ref_shuffle_encode(codes_flat, *, capacity):
-        with obs.span("fz.stage.shuffle_encode", backend="reference"):
-            shuffled = shuffle.bitshuffle(codes_flat)
-            return enc.encode(shuffled, capacity=capacity)
+        return enc.encode(shuffle.bitshuffle(codes_flat), capacity=capacity)
     def ref_unshuffle(words):
-        with obs.span("fz.stage.unshuffle", backend="reference"):
-            return shuffle.bitunshuffle(words.T.reshape(-1))
-    return ref_quant, ref_shuffle_encode, ref_unshuffle
+        return shuffle.bitunshuffle(words.T.reshape(-1))
+    return quant.dual_quantize, ref_shuffle_encode, ref_unshuffle
 
 
 def _source_dtype_name(data: jax.Array) -> str:
@@ -236,21 +238,28 @@ def _path(cfg: FZConfig) -> str:
     return "staged" if cfg.use_kernels else "reference"
 
 
-def _count_dispatch(op: str, cfg: FZConfig, out: FZCompressed | None = None) -> None:
-    """One eager jit launch = one dispatch. Callers gate on
-    ``jax.core.trace_ctx.is_top_level()`` so traces are never tallied as work."""
-    obs.counter("fz_dispatches", op=op, path=_path(cfg)).inc()
-    if out is not None:
-        obs.histogram("fz_raw_bytes", op=op).observe(out.raw_bytes())
-        obs.histogram("fz_wire_bytes", op=op).observe(out.wire_bytes())
+def _dispatch(op: str, span: str, jitted, args: tuple, cfg: FZConfig, size: int,
+              dtype_name: str, **attrs):
+    """``jitted(*args, cfg)`` at the resolved ``cfg``. Eagerly, one launch is
+    one dispatch: the ``span`` holds the resolution, the launch and the
+    ``fz_dispatches`` count. Inside an enclosing trace it is neither timed
+    nor counted."""
+    if not jax.core.trace_ctx.is_top_level():
+        return jitted(*args, _resolved(cfg, op, size, dtype_name))
+    with obs.span(span, **attrs):
+        cfg = _resolved(cfg, op, size, dtype_name)
+        out = jitted(*args, cfg)
+        obs.counter("fz_dispatches", op=op, path=_path(cfg)).inc()
+    return out
 
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _compress_jit(data: jax.Array, cfg: FZConfig) -> FZCompressed:
     cfg = _static_auto(cfg, data.size)
     dtype_name = _source_dtype_name(data)
-    data = data.astype(jnp.float32)
-    eb = resolve_eb(data, cfg)
+    with obs.span("fz.stage.resolve_eb"):
+        data = data.astype(jnp.float32)
+        eb = resolve_eb(data, cfg)
     return _compress_core(data, eb, cfg, dtype_name)
 
 
@@ -260,13 +269,9 @@ def compress(data: jax.Array, cfg: FZConfig) -> FZCompressed:
     The source dtype is recorded in the container (``dtype_name``) for byte
     accounting; the quantization math itself always runs in float32.
     """
-    cfg = _resolved(cfg, "compress", int(data.size), _source_dtype_name(data))
-    if not jax.core.trace_ctx.is_top_level():
-        return _compress_jit(data, cfg)
-    with obs.span("fz.compress", n=int(data.size), path=_path(cfg)):
-        out = _compress_jit(data, cfg)
-    _count_dispatch("compress", cfg, out)
-    return out
+    n = int(data.size)
+    return _dispatch("compress", "fz.compress", _compress_jit, (data,), cfg, n,
+                     _source_dtype_name(data), n=n)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -274,9 +279,11 @@ def _compress_with_eb_jit(data: jax.Array, eb_abs: jax.Array,
                           cfg: FZConfig) -> FZCompressed:
     cfg = _static_auto(cfg, data.size)
     dtype_name = _source_dtype_name(data)
-    data = data.astype(jnp.float32)
-    eb = jnp.maximum(jnp.asarray(eb_abs, jnp.float32), jnp.float32(1e-30))
-    return _compress_core(data, quant.snap_eb(eb), cfg, dtype_name)
+    with obs.span("fz.stage.resolve_eb"):
+        data = data.astype(jnp.float32)
+        eb = quant.snap_eb(jnp.maximum(jnp.asarray(eb_abs, jnp.float32),
+                                       jnp.float32(1e-30)))
+    return _compress_core(data, eb, cfg, dtype_name)
 
 
 def compress_with_eb(data: jax.Array, eb_abs: jax.Array, cfg: FZConfig) -> FZCompressed:
@@ -289,13 +296,9 @@ def compress_with_eb(data: jax.Array, eb_abs: jax.Array, cfg: FZConfig) -> FZCom
     ``eb_abs`` is traced (not baked into ``cfg``), all same-shaped pages share
     a single jit trace.
     """
-    cfg = _resolved(cfg, "compress", int(data.size), _source_dtype_name(data))
-    if not jax.core.trace_ctx.is_top_level():
-        return _compress_with_eb_jit(data, eb_abs, cfg)
-    with obs.span("fz.compress", n=int(data.size), path=_path(cfg)):
-        out = _compress_with_eb_jit(data, eb_abs, cfg)
-    _count_dispatch("compress", cfg, out)
-    return out
+    n = int(data.size)
+    return _dispatch("compress", "fz.compress", _compress_with_eb_jit,
+                     (data, eb_abs), cfg, n, _source_dtype_name(data), n=n)
 
 
 def _compress_core(data: jax.Array, eb: jax.Array, cfg: FZConfig,
@@ -311,14 +314,18 @@ def _compress_core(data: jax.Array, eb: jax.Array, cfg: FZConfig,
                             n_outliers=jnp.minimum(n_over, oidx.size).astype(jnp.int32),
                             eb_abs=eb, shape=tuple(data.shape), dtype_name=dtype_name)
     quantize, shuffle_encode, _ = _stages(cfg)
-    codes, oidx, oval, n_over = quantize(
-        data, eb, code_mode=cfg.code_mode,
-        outlier_capacity=cfg.outlier_capacity(data.size))
-    flat = shuffle.pad_to_tiles(codes.reshape(-1))
-    bitflags, payload, nnz = shuffle_encode(flat, capacity=cfg.payload_capacity(data.size))
+    with obs.span("fz.stage.quantize"):
+        codes, oidx, oval, n_over = quantize(
+            data, eb, code_mode=cfg.code_mode,
+            outlier_capacity=cfg.outlier_capacity(data.size))
+        with obs.span("fz.stage.collect_outliers"):
+            n_outliers = jnp.minimum(n_over, oidx.size).astype(jnp.int32)
+    with obs.span("fz.stage.shuffle_encode"):
+        flat = shuffle.pad_to_tiles(codes.reshape(-1))
+        bitflags, payload, nnz = shuffle_encode(
+            flat, capacity=cfg.payload_capacity(data.size))
     return FZCompressed(bitflags=bitflags, payload=payload, nnz_blocks=nnz,
-                        outlier_idx=oidx, outlier_val=oval,
-                        n_outliers=jnp.minimum(n_over, oidx.size).astype(jnp.int32),
+                        outlier_idx=oidx, outlier_val=oval, n_outliers=n_outliers,
                         eb_abs=eb, shape=tuple(data.shape), dtype_name=dtype_name)
 
 
@@ -333,24 +340,22 @@ def _decompress_jit(c: FZCompressed, cfg: FZConfig) -> jax.Array:
             outlier_idx=c.outlier_idx if cfg.exact_outliers else None,
             outlier_val=c.outlier_val if cfg.exact_outliers else None)
     _, _, unshuffle = _stages(cfg)
-    words = enc.decode_blocks(c.bitflags, c.payload,
-                              n_blocks=FZConfig.n_blocks(c.n))
-    codes = unshuffle(words)[: c.n]
+    with obs.span("fz.stage.decode_blocks"):
+        words = enc.decode_blocks(c.bitflags, c.payload,
+                                  n_blocks=FZConfig.n_blocks(c.n))
+    with obs.span("fz.stage.unshuffle"):
+        codes = unshuffle(words)[: c.n]
     oidx = c.outlier_idx if cfg.exact_outliers else None
     oval = c.outlier_val if cfg.exact_outliers else None
-    return quant.dual_dequantize(codes, c.eb_abs, c.shape, code_mode=cfg.code_mode,
-                                 outlier_idx=oidx, outlier_val=oval)
+    with obs.span("fz.stage.dequantize"):
+        return quant.dual_dequantize(codes, c.eb_abs, c.shape, code_mode=cfg.code_mode,
+                                     outlier_idx=oidx, outlier_val=oval)
 
 
 def decompress(c: FZCompressed, cfg: FZConfig) -> jax.Array:
     """Inverse pipeline: decode -> bit-unshuffle -> inverse Lorenzo -> dequant."""
-    cfg = _resolved(cfg, "decompress", c.n, c.dtype_name)
-    if not jax.core.trace_ctx.is_top_level():
-        return _decompress_jit(c, cfg)
-    with obs.span("fz.decompress", n=c.n, path=_path(cfg)):
-        out = _decompress_jit(c, cfg)
-    _count_dispatch("decompress", cfg)
-    return out
+    return _dispatch("decompress", "fz.decompress", _decompress_jit, (c,), cfg,
+                     c.n, c.dtype_name, n=c.n)
 
 
 def decompress_unmetered(c: FZCompressed, cfg: FZConfig) -> jax.Array:
@@ -358,6 +363,21 @@ def decompress_unmetered(c: FZCompressed, cfg: FZConfig) -> jax.Array:
     sentinels' sampled roundtrip checks, which must not perturb the dispatch
     accounting they audit (same compiled program, bit-identical output)."""
     return _decompress_jit(c, _resolved(cfg, "decompress", c.n, c.dtype_name))
+
+
+def lowered(op: str, arg, cfg: FZConfig) -> jax.stages.Lowered:
+    """The program that ``compress(arg, cfg)`` (``op="compress"``) or
+    ``decompress(arg, cfg)`` (``op="decompress"``) dispatches, lowered: the
+    same jitted inner at the same resolved config. ``arg`` may be a
+    ``jax.ShapeDtypeStruct`` or a container of them. Compiling it hits the
+    cache of the dispatched program, and its compiled text names the
+    instructions a profile of that dispatch shows."""
+    if op == "compress":
+        return _compress_jit.lower(arg, _resolved(cfg, op, int(arg.size),
+                                                  _source_dtype_name(arg)))
+    if op == "decompress":
+        return _decompress_jit.lower(arg, _resolved(cfg, op, arg.n, arg.dtype_name))
+    raise ValueError(f"unknown op {op!r}")
 
 
 def roundtrip(data: jax.Array, cfg: FZConfig):
@@ -382,16 +402,10 @@ def compress_batch_with_eb(pages_flat: jax.Array, eb_abs: jax.Array,
     whole set. Elementwise math at a shared traced bound — each row is
     bit-identical to a single-row ``compress_with_eb`` call. This is the
     kvpool cold tier's batched park path."""
-    cfg = _resolved(cfg, "compress", int(pages_flat.size // pages_flat.shape[0]),
-                    _source_dtype_name(pages_flat))
-    if not jax.core.trace_ctx.is_top_level():
-        return _compress_batch_jit(pages_flat, eb_abs, cfg)
-    with obs.span("fz.compress_batch", rows=int(pages_flat.shape[0]),
-                  path=_path(cfg)):
-        out = _compress_batch_jit(pages_flat, eb_abs, cfg)
-    _count_dispatch("compress", cfg)
-    obs.histogram("fz_wire_bytes", op="compress").observe(out.wire_bytes())
-    return out
+    return _dispatch("compress", "fz.compress_batch", _compress_batch_jit,
+                     (pages_flat, eb_abs), cfg,
+                     int(pages_flat.size // pages_flat.shape[0]),
+                     _source_dtype_name(pages_flat), rows=int(pages_flat.shape[0]))
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -403,14 +417,9 @@ def _decompress_batch_jit(comp: FZCompressed, cfg: FZConfig):
 def decompress_batch(comp: FZCompressed, cfg: FZConfig) -> jax.Array:
     """vmap ``decompress`` over a leaf-stacked container batch (one counted
     dispatch) — the kvpool's batched transient cold read."""
-    cfg = _resolved(cfg, "decompress", comp.n, comp.dtype_name)
-    if not jax.core.trace_ctx.is_top_level():
-        return _decompress_batch_jit(comp, cfg)
-    with obs.span("fz.decompress_batch", rows=int(comp.payload.shape[0]),
-                  path=_path(cfg)):
-        out = _decompress_batch_jit(comp, cfg)
-    _count_dispatch("decompress", cfg)
-    return out
+    return _dispatch("decompress", "fz.decompress_batch", _decompress_batch_jit,
+                     (comp,), cfg, comp.n, comp.dtype_name,
+                     rows=int(comp.payload.shape[0]))
 
 
 # ---------------------------------------------------------------------------

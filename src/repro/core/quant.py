@@ -28,6 +28,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 MAX_MAG = 0x7FFF  # largest representable |delta| in a sign-magnitude u16
 SIGN_BIT = 0x8000
 
@@ -215,18 +217,19 @@ def collect_outliers(resid: jax.Array, outlier_capacity: int):
     them, in flat order, keep their index and exact value; unused slots hold
     index ``n`` and value 0. K = 0 records only the count (paper mode).
     """
-    flat = resid.ravel()
-    n = flat.size
-    over = flat != 0
-    n_over = jnp.sum(over, dtype=jnp.int32)
-    if outlier_capacity > 0:
-        (idx,) = jnp.nonzero(over, size=outlier_capacity, fill_value=n)
-        val = jnp.where(idx < n, flat[jnp.minimum(idx, n - 1)], 0)
-        idx = idx.astype(jnp.int32)
-    else:
-        idx = jnp.zeros((0,), jnp.int32)
-        val = jnp.zeros((0,), jnp.int32)
-    return idx, val, n_over
+    with obs.span("fz.stage.collect_outliers"):
+        flat = resid.ravel()
+        n = flat.size
+        over = flat != 0
+        n_over = jnp.sum(over, dtype=jnp.int32)
+        if outlier_capacity > 0:
+            (idx,) = jnp.nonzero(over, size=outlier_capacity, fill_value=n)
+            val = jnp.where(idx < n, flat[jnp.minimum(idx, n - 1)], 0)
+            idx = idx.astype(jnp.int32)
+        else:
+            idx = jnp.zeros((0,), jnp.int32)
+            val = jnp.zeros((0,), jnp.int32)
+        return idx, val, n_over
 
 
 @partial(jax.jit, static_argnames=("shape", "code_mode"))

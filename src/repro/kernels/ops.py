@@ -23,7 +23,10 @@ the reference stages in repro.core so FZConfig swaps them in transparently
 (see core/fz.py:_stages); the fused wrappers produce whole containers' worth
 of fields per call.
 
-Every stage body runs under an ``obs.span("fz.stage.<name>", backend=...)``.
+The staged wrappers open no span: ``core/fz.py`` opens the
+``fz.stage.<name>`` scope around each call, the same for the kernels and
+the reference. The fused wrappers run under
+``obs.span("fz.stage.fused_compress"/"fused_decompress", backend=...)``.
 These execute while jax is tracing the enclosing fz jit, so they record
 once-per-compilation ``jit-trace`` events (nested, by timestamp, inside the
 eager ``fz.compress``/``fz.decompress`` wrapper span that triggered the
@@ -77,16 +80,15 @@ def lorenzo_quantize(data: jax.Array, eb: jax.Array, *, code_mode: str = "sign_m
     (outlier_capacity > 0) has the kernel also write the int32 residuals,
     which XLA compacts into the exact-outlier side channel.
     """
-    with obs.span("fz.stage.quantize", backend=backend_label()):
-        if outlier_capacity > 0:
-            codes, resid = _lq.lorenzo_quant(
-                data, eb, code_mode=code_mode, with_residual=True,
-                interpret=backend_interpret())
-            return (codes, *_quant.collect_outliers(resid, outlier_capacity))
-        codes = _lq.lorenzo_quant(data, eb, code_mode=code_mode,
-                                  interpret=backend_interpret())
-        zero_i = jnp.zeros((0,), jnp.int32)
-        return codes, zero_i, zero_i, jnp.int32(0)
+    if outlier_capacity > 0:
+        codes, resid = _lq.lorenzo_quant(
+            data, eb, code_mode=code_mode, with_residual=True,
+            interpret=backend_interpret())
+        return (codes, *_quant.collect_outliers(resid, outlier_capacity))
+    codes = _lq.lorenzo_quant(data, eb, code_mode=code_mode,
+                              interpret=backend_interpret())
+    zero_i = jnp.zeros((0,), jnp.int32)
+    return codes, zero_i, zero_i, jnp.int32(0)
 
 
 @partial(jax.jit, static_argnames=("capacity",))
@@ -97,13 +99,11 @@ def bitshuffle_flag_encode(codes_flat: jax.Array, *, capacity: int):
     """
     if codes_flat.size % TILE:
         raise ValueError(f"size {codes_flat.size} not a multiple of TILE={TILE}")
-    with obs.span("fz.stage.shuffle_encode", backend=backend_label()):
-        tiles = codes_flat.reshape(-1, TILE)
-        shuffled, byteflags = _bsf.bitshuffle_flag(
-            tiles, interpret=backend_interpret())
-        flags = byteflags.reshape(-1).astype(bool)
-        return _enc.compact_blocks(
-            flags, shuffled.reshape(_enc.BLOCK_WORDS, -1), capacity=capacity)
+    tiles = codes_flat.reshape(-1, TILE)
+    shuffled, byteflags = _bsf.bitshuffle_flag(tiles, interpret=backend_interpret())
+    flags = byteflags.reshape(-1).astype(bool)
+    return _enc.compact_blocks(
+        flags, shuffled.reshape(_enc.BLOCK_WORDS, -1), capacity=capacity)
 
 
 @jax.jit
@@ -119,10 +119,8 @@ def bitshuffle(codes_flat: jax.Array) -> jax.Array:
 def bitunshuffle(words: jax.Array) -> jax.Array:
     """Inverse transform kernel: word-major (8, n_blocks) shuffled words
     (``core.encode.decode_blocks``) -> flat codes."""
-    with obs.span("fz.stage.unshuffle", backend=backend_label()):
-        tiles = words.reshape(_enc.BLOCK_WORDS, -1, _bsf.BLOCKS_PER_TILE)
-        return _bsf.bitunshuffle_tiles(
-            tiles, interpret=backend_interpret()).reshape(-1)
+    tiles = words.reshape(_enc.BLOCK_WORDS, -1, _bsf.BLOCKS_PER_TILE)
+    return _bsf.bitunshuffle_tiles(tiles, interpret=backend_interpret()).reshape(-1)
 
 
 # ---------------------------------------------------------------------------

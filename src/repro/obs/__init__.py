@@ -10,7 +10,7 @@ per-module ad-hoc counters. Four pieces:
     timed scopes feeding the histograms, a bounded event ring, and
     ``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` so the same names
     appear in real XLA profiles;
-  * :mod:`trace`     — exporters: Chrome ``trace_event`` JSON and JSONL;
+  * :mod:`trace`     — exporter: Chrome ``trace_event`` JSON;
   * :mod:`sentinels` — always-on health monitors (error-bound violations,
     ratio drift, scheduler starvation) behind ``obs.assert_healthy()``.
 
@@ -84,4 +84,4 @@ from .sentinels import (CONFIG, HealthError, SentinelConfig,  # noqa: F401
                         violations)
 from .spans import (clear_events, current_stack, events,  # noqa: F401
                     ring_capacity, set_ring_capacity, span)
-from .trace import chrome_trace, write_chrome_trace, write_jsonl  # noqa: F401
+from .trace import chrome_trace, write_chrome_trace  # noqa: F401

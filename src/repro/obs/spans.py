@@ -5,8 +5,8 @@
   * **metrics** — wall-clock duration lands in the log-bucketed histogram
     ``span_ms{span=<name>}`` and bumps ``span_calls{span=<name>}``;
   * **events** — a completed span appends one event to the bounded in-memory
-    ring (``events()``), which the exporters in ``obs.trace`` turn into a
-    Chrome ``trace_event`` JSON / JSONL log;
+    ring (``events()``), which ``obs.trace`` exports as Chrome
+    ``trace_event`` JSON;
   * **profiler hooks** — the body runs under ``jax.named_scope(name)`` (the
     span name lands in XLA op metadata, so ``hlo_cost.analyze`` tag patterns
     and real XLA profiles see the same names) and, when running eagerly,

@@ -1,18 +1,13 @@
-"""Exporters: Chrome ``trace_event`` JSON (Perfetto-loadable) and JSONL.
+"""Exporter: Chrome ``trace_event`` JSON (Perfetto-loadable).
 
-The span layer's event ring is exporter-agnostic; this module turns it into
-artifacts:
-
-  * :func:`chrome_trace` / :func:`write_chrome_trace` — the Chrome trace
-    format (``chrome://tracing`` / https://ui.perfetto.dev): complete events
-    (``ph="X"``) with microsecond ``ts``/``dur``, one row per thread.
-    Eager spans export under category ``span``; per-compilation trace-time
-    spans under ``jit-trace`` (they appear once, nested inside the eager
-    span that triggered compilation).
-  * :func:`write_jsonl` — one JSON object per line, for ad-hoc grepping and
-    downstream joins.
-
-Both take an explicit event list or default to the live ring.
+The span layer's event ring is exporter-agnostic; :func:`chrome_trace` /
+:func:`write_chrome_trace` turn it into the Chrome trace format
+(``chrome://tracing`` / https://ui.perfetto.dev): complete events
+(``ph="X"``) with microsecond ``ts``/``dur``, one row per thread. Eager spans
+export under category ``span``; per-compilation trace-time spans under
+``jit-trace`` (they appear once, nested inside the eager span that
+triggered compilation). Both take an explicit event list or default to the
+live ring.
 """
 from __future__ import annotations
 
@@ -54,10 +49,3 @@ def write_chrome_trace(path: str, events: list[dict] | None = None,
         json.dump(chrome_trace(events, metadata), f)
     return path
 
-
-def write_jsonl(path: str, events: list[dict] | None = None) -> str:
-    events = _spans.events() if events is None else events
-    with open(path, "w") as f:
-        for ev in events:
-            f.write(json.dumps(ev) + "\n")
-    return path

@@ -245,15 +245,6 @@ def test_chrome_trace_schema(tmp_path):
                                                   for e in ms}
 
 
-def test_write_jsonl(tmp_path):
-    with obs.span("x"):
-        pass
-    p = tmp_path / "events.jsonl"
-    obs.write_jsonl(str(p))
-    lines = [json.loads(l) for l in p.read_text().splitlines()]
-    assert len(lines) == 1 and lines[0]["name"] == "x"
-
-
 # -- sentinels ---------------------------------------------------------------
 
 def test_sentinel_eb_sampling_first_then_every_nth():
