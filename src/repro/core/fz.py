@@ -12,7 +12,7 @@ Three execution paths, selected by ``FZConfig.use_kernels`` /
   * ``use_kernels=False`` — pure-jnp reference (core.quant/shuffle/encode),
     the oracle everything else is pinned against;
   * ``use_kernels=True, kernel_mode="staged"`` — the per-stage Pallas kernels
-    (fused quant kernel, fused shuffle+flag kernel, XLA ``cumsum``/``nonzero``
+    (fused quant kernel, fused shuffle+flag kernel, XLA scan/scatter/gather
     phase-2 epilogue); the u16 code stream round-trips HBM between launches.
     Retained as a second oracle next to the reference;
   * ``use_kernels=True, kernel_mode="fused"`` — one compress megakernel and

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 
 from repro import obs
 
+from . import encode as _enc
+
 MAX_MAG = 0x7FFF  # largest representable |delta| in a sign-magnitude u16
 SIGN_BIT = 0x8000
 
@@ -209,6 +211,22 @@ def dual_quantize(data: jax.Array, eb: jax.Array, *, code_mode: str = "sign_mag"
     return (codes, *collect_outliers(resid, outlier_capacity))
 
 
+OUTLIER_TILE = 1024        # flat tile width where the rows do not serve
+OUTLIER_CHUNK = 1 << 18    # residuals one trip of the compaction loop reads
+
+
+def _outlier_rows(resid: jax.Array) -> jax.Array:
+    """The residual as rows that tile its flat order: the array's own
+    last-axis rows where they are 128 to ``OUTLIER_CHUNK`` wide (no
+    relayout of a tiled array), else the flat residual zero-padded to rows
+    of at most ``OUTLIER_TILE``, a multiple of 128."""
+    if resid.ndim > 1 and 128 <= resid.shape[-1] <= OUTLIER_CHUNK:
+        return resid
+    flat = resid.ravel()
+    width = min(OUTLIER_TILE, -(-flat.size // 128) * 128)
+    return jnp.pad(flat, (0, (-flat.size) % width)).reshape(-1, width)
+
+
 def collect_outliers(resid: jax.Array, outlier_capacity: int):
     """int32 residuals of :func:`to_codes` -> (outlier_idx i32[K],
     outlier_val i32[K], n_outliers i32[]).
@@ -216,20 +234,55 @@ def collect_outliers(resid: jax.Array, outlier_capacity: int):
     A residual is nonzero exactly where the code saturated. The first K of
     them, in flat order, keep their index and exact value; unused slots hold
     index ``n`` and value 0. K = 0 records only the count (paper mode).
+
+    A tile-gated compaction, in time that grows with the outliers: one read
+    of the residual counts each row's outliers (rows of
+    :func:`_outlier_rows`), a scan over the row counts gives each row its
+    first rank, and a loop gathers the rows that hold outliers with a rank
+    below K, ``OUTLIER_CHUNK`` residuals a trip, and scatters their outliers
+    to their ranks. With no outliers the loop makes no trip, and nothing
+    touches the residual but the count.
     """
     with obs.span("fz.stage.collect_outliers"):
-        flat = resid.ravel()
-        n = flat.size
-        over = flat != 0
-        n_over = jnp.sum(over, dtype=jnp.int32)
-        if outlier_capacity > 0:
-            (idx,) = jnp.nonzero(over, size=outlier_capacity, fill_value=n)
-            val = jnp.where(idx < n, flat[jnp.minimum(idx, n - 1)], 0)
-            idx = idx.astype(jnp.int32)
-        else:
-            idx = jnp.zeros((0,), jnp.int32)
-            val = jnp.zeros((0,), jnp.int32)
-        return idx, val, n_over
+        n = resid.size
+        if outlier_capacity == 0:
+            empty = jnp.zeros((0,), jnp.int32)
+            return empty, empty, jnp.sum(resid != 0, dtype=jnp.int32)
+        k = outlier_capacity
+        rows = _outlier_rows(resid)
+        lead, width = rows.shape[:-1], rows.shape[-1]
+        count = jnp.sum(rows != 0, axis=-1, dtype=jnp.int32).reshape(-1)
+        n_rows = count.size
+        first = _enc.exclusive_cumsum(count)
+        live = ((count > 0) & (first < k)).astype(jnp.int32)
+        live_end = _enc.exclusive_cumsum(live) + live
+        n_live = jnp.sum(live)
+        group = max(1, min(n_rows, OUTLIER_CHUNK // width))
+        lane = jnp.arange(width, dtype=jnp.int32)
+        spill = k + jnp.arange(group * width, dtype=jnp.int32).reshape(group, width)
+
+        def trip(carry):
+            t, idx, val = carry
+            with jax.named_scope("outlier_chunk"):
+                j = t * group + jnp.arange(group, dtype=jnp.int32)
+                row = jnp.searchsorted(live_end, j, side="right",
+                                       method="scan_unrolled").astype(jnp.int32)
+                row = jnp.minimum(row, n_rows - 1)
+                r = rows[jnp.unravel_index(row, lead)]
+                hit = (r != 0) & (j < n_live)[:, None]
+                h = hit.astype(jnp.int32)
+                rank = first[row][:, None] + jnp.cumsum(h, axis=1) - h
+                # ranks past K and misses go to distinct slots past the end
+                dst = jnp.where(hit & (rank < k), rank, spill)
+                idx = idx.at[dst].set(row[:, None] * width + lane, mode="drop",
+                                      unique_indices=True)
+                val = val.at[dst].set(r, mode="drop", unique_indices=True)
+                return t + 1, idx, val
+
+        _, idx, val = jax.lax.while_loop(
+            lambda c: c[0] * group < n_live, trip,
+            (jnp.int32(0), jnp.full((k,), n, jnp.int32), jnp.zeros((k,), jnp.int32)))
+        return idx, val, jnp.sum(count)
 
 
 @partial(jax.jit, static_argnames=("shape", "code_mode"))

@@ -18,7 +18,9 @@ first band masks its (clamped) halo to zero via pl.program_id.
 Strict mode (beyond the paper): with ``with_residual=True`` the kernel also
 writes the int32 residual ``delta - decode(code)``, nonzero exactly where a
 code saturated; ``kernels/ops.py`` compacts it into the exact-outlier side
-channel with the reference's own ``core.quant.collect_outliers``.
+channel with the reference's own ``core.quant.collect_outliers``: one read
+of the residual to count each row's outliers, then a loop that gathers only
+the rows holding outliers (no trip when there are none).
 """
 from __future__ import annotations
 

@@ -6,6 +6,7 @@ carries an innermost stage scope of its direction. Instructions the compiler
 makes itself carry no op_name of the program; a profile's reduction assigns
 those (``bench/stages.py``).
 """
+import math
 import re
 
 import jax
@@ -18,6 +19,8 @@ STAGES = {"compress": {"resolve_eb", "quantize", "collect_outliers",
                        "shuffle_encode", "compact_blocks"},
           "decompress": {"decode_blocks", "unshuffle", "dequantize"}}
 OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*([a-z]+\d*\[[\d,]*\])?"
+                   r".*?\s([a-z][\w-]*)\(([^)]*)\)")
 
 
 def _innermost(op_name: str) -> str | None:
@@ -60,3 +63,34 @@ def test_lowered_is_what_the_wrapper_dispatches():
                                                  c.dtype_name)).as_text()
     with pytest.raises(ValueError):
         fz.lowered("both", x, cfg)
+
+
+def _elements(shape: str | None) -> int:
+    dims = shape[shape.index("[") + 1:-1] if shape else ""
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def test_outliers_are_collected_without_a_whole_field_op():
+    """The strict program at 512^3 collects outliers with no scatter and no
+    reduce-window over n elements: ``jnp.nonzero``'s whole-field bincount
+    (a scatter-add of every element, after an n-element cumsum) stays out.
+    Its compaction loop's body is named ``outlier_chunk``, under the stage."""
+    n = 512 ** 3
+    cfg = fz.FZConfig(eb=1e-3, use_kernels=True, kernel_mode="staged")
+    text = fz.lowered("compress", jax.ShapeDtypeStruct((512,) * 3, jnp.float32),
+                      cfg).compile().as_text()
+    shapes, ops, body = {}, [], 0
+    for line in text.splitlines():
+        instr, name = INSTR.match(line), OP_NAME.search(line)
+        if not instr:
+            continue
+        shapes[instr.group(1)] = instr.group(2)
+        if name and _innermost(name.group(1)) == "collect_outliers":
+            body += "/outlier_chunk/" in name.group(1)
+            if instr.group(3) in ("scatter", "reduce-window"):
+                ops.append(instr)
+    for op in ops:
+        operands = re.findall(r"%([\w.\-]+)", op.group(4))
+        sizes = [_elements(shapes.get(o)) for o in [op.group(1), *operands]]
+        assert max(sizes) < n, op.group(0)[:200]
+    assert ops and body
