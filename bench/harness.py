@@ -290,7 +290,11 @@ def memory_peak() -> int | None:
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         require_tpu: bool = True, log=None) -> dict:
-    """One run of ``cell``; returns the result object the CLI prints."""
+    """One run of ``cell``; returns the result object the CLI prints.
+    A configuration of ``kind`` ``train`` runs ``bench/train.py``."""
+    if cell.config.get("kind", "field") == "train":
+        from . import train
+        return train.run(cell, seed, seconds, trace, t_start, require_tpu, log)
     import jax
     from repro.core import fz
     from repro.launch.compile_cache import use_compile_cache
