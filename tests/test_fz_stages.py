@@ -28,7 +28,8 @@ def _innermost(op_name: str) -> str | None:
     return found[-1][len("fz.stage."):] if found else None
 
 
-@pytest.mark.parametrize("shape", [(4, 32, 128), (20000,)], ids=["3d", "1d"])
+@pytest.mark.parametrize("shape", [(4, 32, 128), (40, 360), (20000,)],
+                         ids=["3d", "2d", "1d"])
 @pytest.mark.parametrize("strict", [True, False], ids=["strict", "paper"])
 def test_every_program_op_has_one_stage(shape, strict):
     cfg = fz.FZConfig(eb=1e-3, exact_outliers=strict, use_kernels=True,

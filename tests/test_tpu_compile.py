@@ -11,6 +11,7 @@ so every test worker collects the same tests and only the worker given this
 file loads the TPU library. The code's own backend checks still see the CPU;
 the ``on_tpu`` fixture steers them to the chip's branch.
 """
+import math
 import os
 
 import jax
@@ -83,19 +84,28 @@ def test_bitshuffle_compiles_on_nyx_stream(one_chip, direction):
     assert KERNEL_MARK in text
 
 
-@pytest.mark.parametrize("strict", [False, True], ids=["paper", "strict"])
-def test_staged_pipeline_compiles(one_chip, on_tpu, strict):
+@pytest.mark.parametrize("shape, strict", [
+    pytest.param(ISABEL, False, id="paper"), pytest.param(ISABEL, True, id="strict"),
+    pytest.param(CESM, False, id="cesm-paper"), pytest.param(CESM, True, id="cesm-strict")])
+def test_staged_pipeline_compiles(one_chip, on_tpu, shape, strict):
     """The whole jitted compress and decompress the public wrappers run at
-    100x500x500, as dispatch resolves them on a TPU."""
+    100x500x500 and at 1800x3600, as dispatch resolves them on a TPU. A 2D
+    field reaches ``lorenzo_quant`` as itself, its rows padded to whole
+    bands, and not as a 3D or flattened view."""
     cfg = fz.FZConfig(eb=1e-3, eb_mode="rel", use_kernels=True,
                       exact_outliers=strict)
-    n = ISABEL[0] * ISABEL[1] * ISABEL[2]
+    n = math.prod(shape)
     c_cfg = fz._resolved(cfg, "compress", n, "float32")
     d_cfg = fz._resolved(cfg, "decompress", n, "float32")
     assert (c_cfg.kernel_mode, d_cfg.kernel_mode) == ("staged", "staged")
-    x = _arg(one_chip, ISABEL, jnp.float32)
+    x = _arg(one_chip, shape, jnp.float32)
     comp = fz._compress_jit.lower(x, c_cfg).compile()
     assert KERNEL_MARK in comp.as_text()
+    if len(shape) == 2:
+        rows = -(-shape[0] // lq.ROWS_BAND) * lq.ROWS_BAND
+        calls = [line for line in comp.as_text().splitlines()
+                 if line.lstrip().startswith("%lorenzo_quant") and KERNEL_MARK in line]
+        assert len(calls) == 1 and f"f32[{rows},{shape[1]}]" in calls[0]
     c_abs = jax.eval_shape(lambda d: fz._compress_jit(d, c_cfg), x)
     c_abs = jax.tree.map(lambda a: _arg(one_chip, a.shape, a.dtype), c_abs)
     dec = fz._decompress_jit.lower(c_abs, d_cfg).compile()
